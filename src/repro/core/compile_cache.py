@@ -4,14 +4,46 @@ The paper caches NVRTC-compiled kernels per (kernel, problem size); we cache
 AOT-compiled XLA executables per (kernel, device, problem, dtype, config).
 Timings of the miss path are split the same way Fig 5 splits them:
 wisdom read / compile / load / launch.
+
+That cache lives in one process. :func:`enable_persistent_cache` turns on
+JAX's on-disk compilation cache as well, so a second process on the same
+machine skips the compiles the first one paid for.
 """
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 from dataclasses import dataclass, field
+from pathlib import Path
 from typing import Any, Callable
+
+import jax
+
+#: Where JAX keeps its on-disk compilation cache when the environment says.
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def enable_persistent_cache(default_dir: Path | str) -> Path:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is changed. Otherwise the cache goes to ``default_dir``,
+    which must be a fixed path (no pid, timestamp or temporary name), so
+    that the next process looks where this one wrote.
+
+    Example::
+
+        enable_persistent_cache(Path(__file__).parent / ".jax_cache")
+    """
+    env = os.environ.get(CACHE_DIR_ENV)
+    if env:
+        return Path(env)
+    d = Path(default_dir)
+    d.mkdir(parents=True, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", str(d))
+    return d
 
 
 @dataclass

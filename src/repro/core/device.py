@@ -14,12 +14,14 @@ compiler params must never reach a Triton lowering and vice versa — and
 enters the transfer layer's similarity model (cross-backend predictions
 are possible but confidence-penalized).
 
-Unknown hardware is handled *honestly*: :func:`get_device` used to clone
-TPU-v5e peak numbers for any unrecognized kind with no marker, which made
-the cost model, roofline attribution, and transfer confidence silently
-wrong on new hardware. Unknown kinds now come back flagged
-``estimated=True``; the transfer model floors similarity for estimated
-specs and roofline reports annotate fractions computed against guessed
+Unknown hardware is handled *honestly*. :func:`get_device` on a kind it
+does not know (a transfer target, a recorded dataset's device) returns a
+spec cloned from its backend's baseline and flagged ``estimated=True``;
+the transfer model floors similarity for estimated specs and roofline
+reports annotate fractions computed against guessed peaks. The *live*
+device is different: :func:`current_device` raises
+:class:`UnknownDeviceError` when the attached hardware is not in the
+table, because every measurement on it would be read against guessed
 peaks.
 
 The numeric fields feed the analytical cost model (tuner/costmodel.py).
@@ -239,5 +241,24 @@ def current_device_kind() -> str:
                              getattr(dev, "platform", ""))
 
 
+class UnknownDeviceError(RuntimeError):
+    """The attached device's kind is not in :data:`DEVICES`."""
+
+
 def current_device() -> DeviceSpec:
-    return get_device(current_device_kind())
+    """Spec of the active device.
+
+    An explicit ``KERNEL_LAUNCHER_DEVICE`` resolves like
+    :func:`get_device`. A live device whose kind is not in the table
+    raises :class:`UnknownDeviceError` instead of running on guessed
+    peaks.
+    """
+    kind = current_device_kind()
+    spec = get_device(kind)
+    if spec.estimated and not os.environ.get(DEVICE_ENV):
+        dev = jax.devices()[0]
+        raise UnknownDeviceError(
+            f"live device {dev.device_kind!r} (platform {dev.platform!r}) "
+            f"parses to unknown kind {kind!r}; add it to DEVICES with its "
+            f"published peaks, or set {DEVICE_ENV}")
+    return spec

@@ -11,33 +11,38 @@ happens to be importable.
 
 :func:`lowering_kwargs` is that decision:
 
-* backend ``"tpu"``   -> Mosaic ``TPUCompilerParams(dimension_semantics)``
-* backend ``"gpu"``   -> ``TritonCompilerParams(num_warps, num_stages)``
+* backend ``"tpu"``   -> Mosaic ``pltpu.CompilerParams(dimension_semantics)``
+* backend ``"gpu"``   -> ``pltriton.CompilerParams(num_warps, num_stages)``
 * backend ``"cpu"``   -> no params (the interpreter takes none)
 * ``interpret=True``  -> no params, on any backend (the CI story: GPU
   and TPU lowerings both run under the Pallas interpreter on hosts
   without the hardware, and the interpreter rejects backend params)
 
 Kernels still own their *structural* backend choices (scratch memory,
-grid shape); this module only centralizes the compiler-param gate so no
-kernel can re-grow the ``pltpu is None`` bug.
+grid shape); this module only centralizes the compiler-param gate.
 """
 
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import triton as pltriton
+
 from repro.core.device import current_device
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+__all__ = ["active_backend", "dot_precision", "lowering_kwargs"]
 
-try:
-    from jax.experimental.pallas import triton as pltriton
-except Exception:  # pragma: no cover
-    pltriton = None
 
-__all__ = ["active_backend", "lowering_kwargs"]
+def dot_precision(dtype) -> jax.lax.Precision | None:
+    """MXU precision for a kernel's dots on ``dtype`` inputs.
+
+    Mosaic runs a float32 dot at default precision as one bfloat16 pass
+    (about 3e-3 relative error on a v5e against a float32 reference), so
+    float32 kernels ask for ``HIGHEST``; other dtypes keep the default.
+    """
+    return (jax.lax.Precision.HIGHEST
+            if jnp.dtype(dtype) == jnp.float32 else None)
 
 
 def active_backend() -> str:
@@ -45,26 +50,13 @@ def active_backend() -> str:
     return current_device().backend
 
 
-def _tpu_params(dimension_semantics):
-    cp = getattr(pltpu, "CompilerParams",
-                 getattr(pltpu, "TPUCompilerParams", None))
-    if cp is None:  # pragma: no cover — very old pallas
-        return {}
-    return {"compiler_params":
-            cp(dimension_semantics=tuple(dimension_semantics))}
-
-
 def _gpu_params(num_warps, num_stages):
-    cp = getattr(pltriton, "CompilerParams",
-                 getattr(pltriton, "TritonCompilerParams", None))
-    if cp is None:  # pragma: no cover — pallas without a Triton backend
-        return {}
     kw = {}
     if num_warps is not None:
         kw["num_warps"] = int(num_warps)
     if num_stages is not None:
         kw["num_stages"] = int(num_stages)
-    return {"compiler_params": cp(**kw)}
+    return {"compiler_params": pltriton.CompilerParams(**kw)}
 
 
 def lowering_kwargs(*, dimension_semantics=(), num_warps=None,
@@ -75,8 +67,7 @@ def lowering_kwargs(*, dimension_semantics=(), num_warps=None,
     ``dimension_semantics`` feeds the Mosaic (TPU) params; ``num_warps``
     and ``num_stages`` feed the Triton (GPU) params — callers pass both
     sets and exactly one (or neither) is used. Returns ``{}`` under
-    ``interpret`` and on backends whose param class is unavailable, so
-    the call site never needs its own availability guard.
+    ``interpret`` and on the CPU.
 
     Example::
 
@@ -88,8 +79,9 @@ def lowering_kwargs(*, dimension_semantics=(), num_warps=None,
     if interpret:
         return {}
     b = backend if backend is not None else active_backend()
-    if b == "tpu" and pltpu is not None and dimension_semantics:
-        return _tpu_params(dimension_semantics)
-    if b == "gpu" and pltriton is not None:
+    if b == "tpu" and dimension_semantics:
+        return {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=tuple(dimension_semantics))}
+    if b == "gpu":
         return _gpu_params(num_warps, num_stages)
     return {}

@@ -2,11 +2,15 @@
 
 TPU adaptation of the paper's MicroHH kernels: the X axis is the contiguous
 lane dimension and is kept whole inside each block; the grid tiles (Z, Y).
-Halos are passed as *separate side-slab refs* (fixed thickness
-``HALO_BLK = 4`` ≥ the stencil radius 3) with wrapped (periodic) index maps —
-TPU has no overlapping BlockSpec reads, so each field arrives as five refs:
+Halos are passed as *separate side-slab refs* (each at least the stencil
+radius 3 thick) with wrapped (periodic) index maps — TPU has no overlapping
+BlockSpec reads, so each field arrives as five refs:
 
-    center (bz, by, X), z-lo (4, by, X), z-hi, y-lo (bz, 4, X), y-hi
+    center (bz, by, X), z-lo (4, by, X), z-hi, y-lo (bz, 8, X), y-hi
+
+Y is the sublane dimension of every block, so the y-halo is one full
+8-row sublane tile (``HALO_Y = 8``); Mosaic refuses a 4-row block there.
+Z is a leading (untiled) dimension and keeps the thinner ``HALO_Z = 4``.
 
 The stencil math only ever shifts along one axis at a time, so no corner
 slabs are needed. Inside the kernel, per-axis extended views are assembled by
@@ -21,7 +25,8 @@ from typing import Callable
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-HALO_BLK = 4          # side-slab thickness (covers stencil radius <= 4)
+HALO_Z = 4            # z side-slab thickness (leading dim, untiled)
+HALO_Y = 8            # y side-slab thickness (one sublane tile)
 STENCIL_RADIUS = 3    # 5th-order interpolation reach
 
 
@@ -45,8 +50,8 @@ def field_specs(problem: tuple[int, int, int], bz: int, by: int,
                 to_zy: Callable) -> list[pl.BlockSpec]:
     """The five BlockSpecs (center, z-lo, z-hi, y-lo, y-hi) for one field."""
     nz, ny, nx = problem
-    hz, hy = nz // HALO_BLK, ny // HALO_BLK
-    rz, ry = bz // HALO_BLK, by // HALO_BLK
+    hz, hy = nz // HALO_Z, ny // HALO_Y
+    rz, ry = bz // HALO_Z, by // HALO_Y
 
     def center(a, b):
         iz, iy = to_zy(a, b)
@@ -70,10 +75,10 @@ def field_specs(problem: tuple[int, int, int], bz: int, by: int,
 
     return [
         pl.BlockSpec((bz, by, nx), center),
-        pl.BlockSpec((HALO_BLK, by, nx), z_lo),
-        pl.BlockSpec((HALO_BLK, by, nx), z_hi),
-        pl.BlockSpec((bz, HALO_BLK, nx), y_lo),
-        pl.BlockSpec((bz, HALO_BLK, nx), y_hi),
+        pl.BlockSpec((HALO_Z, by, nx), z_lo),
+        pl.BlockSpec((HALO_Z, by, nx), z_hi),
+        pl.BlockSpec((bz, HALO_Y, nx), y_lo),
+        pl.BlockSpec((bz, HALO_Y, nx), y_hi),
     ]
 
 
@@ -113,10 +118,10 @@ class FieldView:
 
     def sy(self, s: int, rows: slice | None = None):
         a = self.ext_y if rows is None else self.ext_y[rows]
-        return a[:, HALO_BLK + s: HALO_BLK + s + self.by, :]
+        return a[:, HALO_Y + s: HALO_Y + s + self.by, :]
 
     def sz(self, s: int, rows: slice | None = None):
-        lo = HALO_BLK + s + (0 if rows is None else rows.start)
+        lo = HALO_Z + s + (0 if rows is None else rows.start)
         n = self.bz if rows is None else rows.stop - rows.start
         return self.ext_z[lo: lo + n]
 
@@ -124,10 +129,10 @@ class FieldView:
 def check_blocks(problem: tuple[int, int, int], bz: int, by: int) -> bool:
     """Static feasibility of a (bz, by) tiling for a (nz, ny, nx) problem."""
     nz, ny, _ = problem
-    return (divides(HALO_BLK, bz) and divides(HALO_BLK, by)
+    return (divides(HALO_Z, bz) and divides(HALO_Y, by)
             and bz <= nz and by <= ny
             and divides(bz, nz) and divides(by, ny)
-            and divides(HALO_BLK, nz) and divides(HALO_BLK, ny))
+            and divides(HALO_Z, nz) and divides(HALO_Y, ny))
 
 
 def stencil_vmem_bytes(problem, bz: int, by: int, n_in_fields: int,
@@ -135,7 +140,7 @@ def stencil_vmem_bytes(problem, bz: int, by: int, n_in_fields: int,
                        buffers: int = 2) -> int:
     """Per-program VMEM working set for the 5-ref stencil layout."""
     nx = problem[2]
-    per_field = (bz * by + 2 * HALO_BLK * by + 2 * bz * HALO_BLK) * nx
+    per_field = (bz * by + 2 * HALO_Z * by + 2 * bz * HALO_Y) * nx
     out = bz * by * nx
     return (n_in_fields * per_field + n_out_fields * out) \
         * dtype_bytes * buffers
@@ -145,6 +150,6 @@ def stencil_hbm_bytes(problem, bz: int, by: int, n_in_fields: int,
                       n_out_fields: int, dtype_bytes: int) -> float:
     nz, ny, nx = problem
     pts = nz * ny * nx
-    halo_overhead = 2 * HALO_BLK / bz + 2 * HALO_BLK / by
+    halo_overhead = 2 * HALO_Z / bz + 2 * HALO_Y / by
     return (n_in_fields * pts * (1.0 + halo_overhead)
             + n_out_fields * pts) * dtype_bytes

@@ -20,23 +20,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import KernelBuilder, Workload, register
 from repro.core.builder import probe_array
 
 from . import ref as _ref
-from ._lowering import active_backend, lowering_kwargs
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from ._lowering import active_backend, dot_precision, lowering_kwargs
 
 NEG_INF = -1e30
 
 
 def _fa_kernel(causal: bool, scale: float, nk: int, bq: int, bk: int,
-               q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref):
+               precision, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref):
     qb = pl.program_id(1)
     kb = pl.program_id(2)
 
@@ -51,7 +47,7 @@ def _fa_kernel(causal: bool, scale: float, nk: int, bq: int, bk: int,
         k = k_ref[0].astype(jnp.float32)            # (bk, D)
         v = v_ref[0].astype(jnp.float32)            # (bk, D)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (1,)), ((), ())), precision=precision,
             preferred_element_type=jnp.float32) * scale   # (bq, bk)
         if causal:
             q_pos = qb * bq + jax.lax.broadcasted_iota(jnp.int32,
@@ -66,7 +62,7 @@ def _fa_kernel(causal: bool, scale: float, nk: int, bq: int, bk: int,
         alpha = jnp.exp(m_prev - m_new)              # (bq, 1)
         l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p, v, (((1,), (0,)), ((), ())), precision=precision,
             preferred_element_type=jnp.float32)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -117,11 +113,10 @@ def _make_builder(causal: bool) -> KernelBuilder:
             dimension_semantics=(config["dim_semantics"],) * 2
             + ("arbitrary",),
             interpret=interpret)
-        if pltpu is None:  # pragma: no cover
-            raise RuntimeError("pallas TPU backend unavailable")
 
         call = pl.pallas_call(
-            functools.partial(_fa_kernel, causal, scale, gk, bq, bk),
+            functools.partial(_fa_kernel, causal, scale, gk, bq, bk,
+                              dot_precision(meta[0].dtype)),
             grid=(BH, gq, gk),
             in_specs=[
                 pl.BlockSpec((1, bq, D), lambda h, iq, ik: (h, iq, 0)),

@@ -15,17 +15,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import KernelBuilder, Workload, register
 from repro.core.builder import probe_array
 
 from . import ref as _ref
-from ._lowering import active_backend, lowering_kwargs
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from ._lowering import active_backend, dot_precision, lowering_kwargs
 
 
 builder = KernelBuilder("matmul", source="repro.kernels.matmul")
@@ -42,14 +38,14 @@ def _problem(a, b):
     return (m, n, k)
 
 
-def _mm_kernel(nk: int, a_ref, b_ref, o_ref, acc_ref):
+def _mm_kernel(nk: int, precision, a_ref, b_ref, o_ref, acc_ref):
     kb = pl.program_id(2)
 
     @pl.when(kb == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
+    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...], precision=precision,
                             preferred_element_type=jnp.float32)
 
     @pl.when(kb == nk - 1)
@@ -130,12 +126,10 @@ def _build(config, problem, meta, interpret: bool = False):
         interpret=interpret, backend=backend)
 
     dtype = meta[0].dtype
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU backend unavailable")
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
 
     call = pl.pallas_call(
-        functools.partial(_mm_kernel, gk),
+        functools.partial(_mm_kernel, gk, dot_precision(dtype)),
         grid=grid,
         in_specs=[pl.BlockSpec((bm, bk), a_map),
                   pl.BlockSpec((bk, bn), b_map)],

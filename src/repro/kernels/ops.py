@@ -54,6 +54,36 @@ def matmul(a, b):
     return matmul_kernel(a, b)
 
 
+#: Always-on tally of the route every :func:`attention` call took:
+#: ``"flash"``, or ``"reference:<reason>"`` for the jnp oracle. A call
+#: under an outer ``jax.jit`` counts once per trace, like
+#: ``WisdomKernel.tier_counts``.
+attention_routes: dict[str, int] = {}
+
+
+def _attention_route(Sq: int, Sk: int, D: int, window, softcap, scale,
+                     kv_offset) -> str:
+    if resolve_backend() not in ("pallas", "interpret"):
+        return "reference:backend"
+    # flash has a TPU (Mosaic) lowering only — on GPU devices the
+    # full-featured jnp oracle serves instead (docs/gpu-backend.md)
+    if current_device().backend == "gpu":
+        return "reference:gpu"
+    if window is not None:
+        return "reference:window"
+    if softcap is not None:
+        return "reference:softcap"
+    if scale is not None and abs(scale - D ** -0.5) >= 1e-12:
+        return "reference:scale"
+    if kv_offset != 0:
+        return "reference:kv_offset"
+    if Sq != Sk:
+        return "reference:sq_ne_sk"
+    if Sq % 128 or D % 128:
+        return "reference:shape"
+    return "flash"
+
+
 def attention(q, k, v, *, causal: bool = True, window: int | None = None,
               softcap: float | None = None, scale: float | None = None,
               kv_offset: int = 0):
@@ -61,20 +91,13 @@ def attention(q, k, v, *, causal: bool = True, window: int | None = None,
 
     Routes to the Pallas flash kernel when the feature set and shapes allow;
     otherwise the full-featured jnp oracle (always the case on CPU hosts).
+    Each call's route is tallied in :data:`attention_routes`.
     """
     B, Hq, Sq, D = q.shape
     Sk = k.shape[2]
-    default_scale = scale is None or abs(scale - D ** -0.5) < 1e-12
-    flashable = (
-        resolve_backend() in ("pallas", "interpret")
-        # flash has a TPU (Mosaic) lowering only — on GPU devices the
-        # full-featured jnp oracle serves instead (docs/gpu-backend.md)
-        and current_device().backend != "gpu"
-        and window is None and softcap is None and default_scale
-        and kv_offset == 0 and Sq == Sk
-        and Sq % 128 == 0 and D % 128 == 0
-    )
-    if not flashable:
+    route = _attention_route(Sq, Sk, D, window, softcap, scale, kv_offset)
+    attention_routes[route] = attention_routes.get(route, 0) + 1
+    if route != "flash":
         return ref.attention_ref(q, k, v, causal=causal, window=window,
                                  softcap=softcap, scale=scale,
                                  kv_offset=kv_offset)

@@ -12,10 +12,13 @@ crashing configs and simply record them as infeasible.
 
 The ``fork`` start method is deliberate: nothing is pickled on the way
 in (closures over builders and numpy arrays just work), and the child
-inherits the warm parent state instead of re-importing jax. The
-``inline`` method skips process isolation (exceptions are still mapped
-to verdicts) — it is the right default where the evaluator is pure
-Python arithmetic (cost model) and forking per config would dominate.
+inherits the warm parent state instead of re-importing jax. It is for
+CPU hosts only: a chip belongs to the process that opened it, so a child
+forked from that process cannot use it, and ``sandboxed_call`` refuses to
+fork once an accelerator backend is live. The ``inline`` method skips
+process isolation (exceptions are still mapped to verdicts) — it is the
+right default where the evaluator is pure Python arithmetic (cost model)
+and forking per config would dominate, and the only method on a chip.
 """
 
 from __future__ import annotations
@@ -141,6 +144,15 @@ def memory_ceiling(extra_bytes: int = 512 * 2**20) -> int:
     return current + int(extra_bytes)
 
 
+def live_accelerator_backends() -> list[str]:
+    """Non-CPU JAX backends this process has initialised (initialises
+    none itself)."""
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return []
+    return sorted(p for p in xla_bridge.backends() if p != "cpu")
+
+
 def _read_stderr(path: str) -> str:
     try:
         with open(path, "r", errors="replace") as f:
@@ -156,8 +168,10 @@ def sandboxed_call(fn: Callable[[], Any],
 
     ``payload`` is ``fn``'s return value when the verdict is ``ok`` and
     None otherwise. With ``method="fork"`` the return value crosses a
-    pipe, so it must be picklable; with ``method="inline"`` anything
-    goes (and only exceptions — not hangs or signals — are contained).
+    pipe, so it must be picklable, and the call raises ``RuntimeError``
+    when this process holds an accelerator backend; with
+    ``method="inline"`` anything goes (and only exceptions — not hangs or
+    signals — are contained).
 
     Example::
 
@@ -185,6 +199,12 @@ def sandboxed_call(fn: Callable[[], Any],
                 stderr=traceback.format_exc()[-STDERR_LIMIT:],
                 wall_s=time.perf_counter() - t0), None)
 
+    live = live_accelerator_backends()
+    if live:
+        raise RuntimeError(
+            f"sandboxed_call(method='fork') refused: this process holds "
+            f"the {', '.join(live)} backend, and a forked child cannot use "
+            f"the chip; use SandboxSettings(method='inline')")
     ctx = multiprocessing.get_context("fork")
     parent_conn, child_conn = ctx.Pipe(duplex=False)
     with tempfile.NamedTemporaryFile(prefix="sandbox-stderr-",

@@ -30,6 +30,7 @@ from __future__ import annotations
 from repro.core.builder import KernelBuilder
 from repro.core.param import Config
 from repro.core.registry import get_kernel
+from repro.tuner.runner import default_interpret
 
 from .evaluator import SandboxSettings
 from .oracle import CorrectnessOracle
@@ -59,8 +60,9 @@ class OracleGate:
     returns the :class:`SandboxVerdict`; ``allows`` maps a verdict to a
     promote/reject decision under the ``on_unverifiable`` policy;
     ``stamp`` adds the ``verified`` provenance block to a passing
-    record's provenance. ``settings=None`` verifies in-process (the
-    interpret-mode check cannot hang); pass fork
+    record's provenance. ``interpret=None`` runs the native lowering on
+    a TPU and interpret mode elsewhere. ``settings=None`` verifies
+    in-process; on a CPU host, pass fork
     :class:`~repro.sandbox.evaluator.SandboxSettings` to also contain
     kernels that crash the process during the check.
 
@@ -73,14 +75,15 @@ class OracleGate:
             provenance = gate.stamp(provenance, "matmul", verdict)
     """
 
-    def __init__(self, interpret: bool = True,
+    def __init__(self, interpret: bool | None = None,
                  settings: SandboxSettings | None = None,
                  on_unverifiable: str = "allow") -> None:
         if on_unverifiable not in ("allow", "reject"):
             raise ValueError(f"unknown on_unverifiable policy "
                              f"{on_unverifiable!r}; use 'allow' or "
                              f"'reject'")
-        self.interpret = interpret
+        self.interpret = (default_interpret() if interpret is None
+                          else interpret)
         self.settings = settings
         self.on_unverifiable = on_unverifiable
         #: Every check this gate made: (kernel, scenario-ish key,

@@ -1,8 +1,8 @@
 """CorrectnessOracle: does a surviving candidate compute the right thing?
 
 The sandbox proves a config *runs*; the oracle proves it runs
-*correctly*. Each check executes the built kernel (interpret mode by
-default, so it works on any host) on concrete probe arguments and
+*correctly*. Each check executes the built kernel (the native lowering
+on a TPU, interpret mode on any other host) on concrete probe arguments and
 compares against the kernel's pure-jnp reference via
 :func:`repro.tuner.runner.verify_outcome` with dtype-aware rtol/atol —
 the KTT-style reference-output validation the tuning literature treats
@@ -20,7 +20,8 @@ import numpy as np
 from repro.core.builder import KernelBuilder
 from repro.core.param import Config
 from repro.obs import runtime as obs
-from repro.tuner.runner import VerifyOutcome, verify_outcome
+from repro.tuner.runner import (VerifyOutcome, default_interpret,
+                                verify_outcome)
 
 from .evaluator import SandboxSettings, sandboxed_call
 from .verdict import (STATUS_CRASH, STATUS_NUMERICS, STATUS_OK,
@@ -64,13 +65,14 @@ class CorrectnessOracle:
 
     def __init__(self, builder: KernelBuilder,
                  args: Sequence[np.ndarray],
-                 interpret: bool = True,
+                 interpret: bool | None = None,
                  settings: SandboxSettings | None = None) -> None:
         self.builder = builder
         self.args = [np.asarray(a) for a in args]
-        self.interpret = interpret
-        #: None = verify in-process (interpret-mode execution cannot
-        #: hang); pass fork settings to also contain hard crashes.
+        self.interpret = (default_interpret() if interpret is None
+                          else interpret)
+        #: None = verify in-process; pass fork settings (CPU hosts only)
+        #: to also contain hard crashes.
         self.settings = settings
         self.verdicts: dict[tuple, SandboxVerdict] = {}
 

@@ -1,10 +1,13 @@
 """Evaluators: config -> (score, validity) — the tuner's measurement step.
 
 ``CostModelEvaluator`` scores a config with the analytical simulated-TPU
-model; ``WallClockEvaluator`` actually executes the built kernel (interpret
-mode on CPU, native Pallas on TPU) and times it. Both optionally *verify* the
-kernel's output against the ``ref.py`` oracle on replayed capture data —
-the paper's "output verification" option in Kernel Tuner.
+model; ``WallClockEvaluator`` executes the built kernel on the TPU and times
+it (it refuses to run without one unless interpret mode is asked for
+explicitly). Both optionally *verify* the kernel's output against the
+``ref.py`` oracle on replayed capture data — the paper's "output
+verification" option in Kernel Tuner. Verification runs the native lowering
+on a TPU backend and interpret mode elsewhere, and the oracle runs at
+``highest`` matmul precision so a float32 reference is float32 on the chip.
 
 Both evaluators also take ``record_to``: any object with a
 ``record(config, EvalResult)`` method — in practice a
@@ -56,12 +59,18 @@ class EvalResult:
     info: dict = field(default_factory=dict)
 
 
-def _tolerances(dtype: str) -> tuple[float, float]:
+def tolerances(dtype: str) -> tuple[float, float]:
     if dtype in ("bfloat16",):
         return 2e-2, 2e-2
     if dtype in ("float16",):
         return 1e-2, 1e-2
     return 1e-5, 1e-5
+
+
+def default_interpret() -> bool:
+    """Interpret mode unless the default backend is a TPU: what a check
+    with no explicit ``interpret`` argument runs."""
+    return jax.default_backend() != "tpu"
 
 
 @dataclass
@@ -93,11 +102,12 @@ class VerifyOutcome:
 
 def verify_outcome(builder: KernelBuilder, config: Config,
                    args: Sequence[np.ndarray],
-                   interpret: bool = True) -> VerifyOutcome:
+                   interpret: bool | None = None) -> VerifyOutcome:
     """Execute the built kernel on ``args``, compare with the reference
     oracle, and classify what happened (see :class:`VerifyOutcome`).
+    ``interpret=None`` means :func:`default_interpret`.
 
-    The comparison is dtype-aware (:func:`_tolerances`) and scales the
+    The comparison is dtype-aware (:func:`tolerances`) and scales the
     absolute tolerance by the reference magnitude, so low-precision
     kernels are judged against realistic accumulation error rather than
     float32 expectations.
@@ -107,9 +117,11 @@ def verify_outcome(builder: KernelBuilder, config: Config,
         out = verify_outcome(get_kernel("matmul"), config, [a, b])
         assert out.ok, out.error
     """
+    if interpret is None:
+        interpret = default_interpret()
     meta = args_meta(*args)
     dtype = builder.get_dtype(*meta)
-    rtol, atol = _tolerances(dtype)
+    rtol, atol = tolerances(dtype)
     try:
         fn = builder.make(config, meta, interpret=interpret)
         got = jax.tree.map(np.asarray, fn(*args))
@@ -118,7 +130,8 @@ def verify_outcome(builder: KernelBuilder, config: Config,
                              error=f"build/run failed: "
                                    f"{type(e).__name__}: {e}")
     ref_fn = builder.make_reference()
-    want = jax.tree.map(np.asarray, ref_fn(*args))
+    with jax.default_matmul_precision("highest"):
+        want = jax.tree.map(np.asarray, ref_fn(*args))
     got_leaves = jax.tree.leaves(got)
     want_leaves = jax.tree.leaves(want)
     if len(got_leaves) != len(want_leaves):
@@ -145,7 +158,8 @@ def verify_outcome(builder: KernelBuilder, config: Config,
 
 def verify_against_reference(builder: KernelBuilder, config: Config,
                              args: Sequence[np.ndarray],
-                             interpret: bool = True) -> tuple[bool, str]:
+                             interpret: bool | None = None
+                             ) -> tuple[bool, str]:
     """Execute the built kernel on ``args`` and compare with the oracle.
 
     Compatibility wrapper over :func:`verify_outcome` returning the
@@ -239,14 +253,15 @@ class CostModelEvaluator:
 
 
 class WallClockEvaluator:
-    """Measure actual execution time (real hardware, or interpret mode).
+    """Measure execution time on the TPU.
 
     Builds and jits the kernel for each config, runs a warmup plus
     ``repeats`` timed executions on the concrete ``args`` (typically a
-    capture's replayed data), and scores the best of the repeats — the
-    paper's measured objective. On non-TPU hosts it falls back to Pallas
-    interpret mode automatically, so the same tuning script runs
-    anywhere (slowly, but with real execution semantics).
+    capture's replayed data, placed on the device once), and scores the
+    best of the repeats — the paper's measured objective. A config the
+    compiler refuses is infeasible. Without a TPU it raises instead of
+    timing something else; ``interpret=True`` opts into timing the Pallas
+    interpreter, which measures the host, not the chip.
 
     Example::
 
@@ -258,10 +273,16 @@ class WallClockEvaluator:
     def __init__(self, builder: KernelBuilder, args: Sequence[np.ndarray],
                  interpret: bool | None = None, repeats: int = 3,
                  verify: bool = True, record_to=None) -> None:
+        if interpret is None:
+            if default_interpret():
+                raise RuntimeError(
+                    f"WallClockEvaluator times the TPU, and the default "
+                    f"backend is {jax.default_backend()!r}; pass "
+                    f"interpret=True to time the Pallas interpreter")
+            interpret = False
         self.builder = builder
         self.args = [np.asarray(a) for a in args]
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+        self._device_args = None
         self.interpret = interpret
         self.repeats = repeats
         self.verify = verify
@@ -289,11 +310,14 @@ class WallClockEvaluator:
         try:
             fn = self.builder.make(config, meta, interpret=self.interpret)
             compiled = jax.jit(fn).lower(*meta).compile()
-            compiled(*self.args)  # warmup
+            if self._device_args is None:
+                self._device_args = jax.device_put(self.args)
+            args = self._device_args
+            jax.block_until_ready(compiled(*args))  # warmup
             times = []
             for _ in range(self.repeats):
                 t0 = time.perf_counter()
-                jax.block_until_ready(compiled(*self.args))
+                jax.block_until_ready(compiled(*args))
                 times.append(time.perf_counter() - t0)
             score_us = min(times) * 1e6
             info: dict = {}

@@ -77,7 +77,8 @@ def test_tuned_config_beats_default_on_simulated_device(
     from repro.tuner import CostModelEvaluator
     from repro.core import get_device
     b = get_kernel("advec_u")
-    ev = CostModelEvaluator(b, (16, 16, 128), "float32",
+    # the captured scenario: the default is scored where it is replaced
+    ev = CostModelEvaluator(b, u.shape, "float32",
                             get_device("tpu-v5e"), verify="none")
     default_score = ev(b.default_config()).score_us
     monkeypatch.setenv(CAPTURE_ENV, "advec_u")

@@ -109,6 +109,23 @@ def test_unknown_kind_is_estimated_not_silently_v5e():
         capability_vector(TPU_V5E)[:3]
 
 
+def test_unknown_live_device_raises(monkeypatch):
+    from types import SimpleNamespace
+
+    import jax
+
+    from repro.core.device import UnknownDeviceError, current_device
+
+    monkeypatch.delenv("KERNEL_LAUNCHER_DEVICE", raising=False)
+    fake = SimpleNamespace(device_kind="TPU v9 ultra", platform="tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [fake])
+    with pytest.raises(UnknownDeviceError, match="tpu-v9-ultra"):
+        current_device()
+    # an explicit kind string keeps resolving to an estimated spec
+    monkeypatch.setenv("KERNEL_LAUNCHER_DEVICE", "tpu-v9-ultra")
+    assert current_device().estimated
+
+
 # -------------------------------------------------- cross-backend model -----
 
 def _raw_similarity(model: DeviceModel) -> float:
@@ -276,9 +293,7 @@ def test_lowering_kwargs_per_backend():
     gpu = lowering_kwargs(dimension_semantics=ds, num_warps=4,
                           num_stages=2, backend="gpu")
     cp = gpu["compiler_params"]
-    triton_cls = getattr(pltriton, "CompilerParams",
-                         getattr(pltriton, "TritonCompilerParams", None))
-    assert isinstance(cp, triton_cls)
+    assert isinstance(cp, pltriton.CompilerParams)
     # Mosaic-only kwargs never leak across the backend boundary
     assert not hasattr(cp, "dimension_semantics")
     assert lowering_kwargs(dimension_semantics=ds, backend="cpu") == {}

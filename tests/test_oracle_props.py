@@ -11,7 +11,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.builder import KernelBuilder
 from repro.sandbox import CorrectnessOracle
-from repro.tuner.runner import _tolerances
+from repro.tuner.runner import tolerances
 
 DTYPES = ["float32", "float16", "bfloat16"]
 
@@ -65,7 +65,7 @@ def test_reference_accepts_itself(data):
     verdict = _check(0.0, x, dtype)
     assert verdict.ok, verdict.detail
     assert verdict.max_err == 0.0
-    rtol, atol = _tolerances(dtype)
+    rtol, atol = tolerances(dtype)
     assert (verdict.rtol, verdict.atol) == (rtol, atol)
 
 
@@ -74,7 +74,7 @@ def test_reference_accepts_itself(data):
 def test_perturbation_within_tolerance_accepted(data):
     x = _probe(data)
     dtype = data.draw(st.sampled_from(DTYPES))
-    rtol, atol = _tolerances(dtype)
+    rtol, atol = tolerances(dtype)
     # |x| <= 1 means every element's allowed deviation is at least atol
     delta = atol * data.draw(st.floats(0.0, 0.5))
     verdict = _check(delta, x, dtype)
@@ -87,7 +87,7 @@ def test_perturbation_within_tolerance_accepted(data):
 def test_perturbation_above_tolerance_rejected(data):
     x = _probe(data)
     dtype = data.draw(st.sampled_from(DTYPES))
-    rtol, atol = _tolerances(dtype)
+    rtol, atol = tolerances(dtype)
     # |x| <= 1 bounds every element's allowed deviation by atol + rtol,
     # so anything safely past that must trip the oracle
     delta = (atol + rtol) * data.draw(st.floats(1.5, 100.0))

@@ -289,3 +289,18 @@ def test_demo_gauntlet_passes():
     assert report["sandbox"]["hang"]["status"] == "timeout"
     assert report["sandbox"]["segv"]["exit_cause"].startswith("signal:")
     assert report["oracle"]["wrong"]["status"] == "numerics-mismatch"
+
+
+def test_fork_sandbox_refuses_once_an_accelerator_is_live(monkeypatch):
+    import repro.sandbox.evaluator as ev
+    monkeypatch.setattr(ev, "live_accelerator_backends", lambda: ["tpu"])
+    with pytest.raises(RuntimeError, match="forked child cannot use"):
+        sandboxed_call(lambda: 1, _fork())
+    # inline evaluation stays available on the chip
+    verdict, out = sandboxed_call(lambda: 1, SandboxSettings(method="inline"))
+    assert verdict.ok and out == 1
+
+
+def test_live_accelerator_backends_sees_only_cpu_here():
+    from repro.sandbox.evaluator import live_accelerator_backends
+    assert live_accelerator_backends() == []

@@ -93,3 +93,32 @@ def test_c5_first_launch_compiles_then_caches(wisdom_dir, small_fields):
     u2, v2, w2 = u[:8], v[:8], w[:8]
     k(u2, v2, w2, scal)
     assert not k.stats[2].cached
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_persistent_cache_goes_where_the_environment_says(tmp_path,
+                                                          env_set):
+    """JAX_COMPILATION_CACHE_DIR wins; unset, the fixed default holds the
+    entries. Run in a child so this process never enables the cache."""
+    import os
+    import subprocess
+    import sys
+    env_dir, default_dir = tmp_path / "env", tmp_path / "default"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_set:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    code = ("import sys, jax, jax.numpy as jnp\n"
+            "from repro.core.compile_cache import enable_persistent_cache\n"
+            "print(enable_persistent_cache(sys.argv[1]))\n"
+            "jax.jit(lambda x: jnp.sin(x) * 2)(jnp.ones(4)).block_until_ready()\n")
+    proc = subprocess.run([sys.executable, "-c", code, str(default_dir)],
+                          env=env, capture_output=True, text=True,
+                          timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    used, unused = ((env_dir, default_dir) if env_set
+                    else (default_dir, env_dir))
+    assert proc.stdout.strip() == str(used)
+    assert any(used.iterdir())
+    assert not unused.exists() or not any(unused.iterdir())

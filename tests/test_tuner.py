@@ -222,3 +222,15 @@ def test_cost_model_noise_deterministic():
     c = m.time(w, "float32", noise_key="k2")
     assert a == b
     assert a != c
+
+
+def test_wallclock_evaluator_refuses_without_a_tpu():
+    from repro.tuner import WallClockEvaluator
+    b = get_kernel("matmul")
+    args = [np.ones((128, 128), np.float32)] * 2
+    with pytest.raises(RuntimeError, match="times the TPU"):
+        WallClockEvaluator(b, args)
+    # the interpreter stays available when asked for by name
+    ev = WallClockEvaluator(b, args, interpret=True, repeats=1)
+    r = ev(b.default_config())
+    assert r.feasible and r.verified, r.error
