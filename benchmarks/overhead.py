@@ -6,11 +6,13 @@ the XLA JIT standing in for NVRTC.
 telemetry layer (repro.obs) sits directly on the launch hot path, which
 is only acceptable if the disabled path costs nothing measurable. The
 gate microbenchmarks one disabled instrument site (a ``metrics()``
-global read plus an ``is not None`` branch) against the pinned budget
-below and exits non-zero when it is blown — CI runs this on every
-change. The profiler (repro.prof) sits on the same hot path, so the
-gate also pins its detached site (attribute read + branch) and the
-amortized cost of sampled profiling at the default 1-in-16 period.
+global read plus an ``is not None`` branch) and one disabled span site
+(``with obs.span(...)``, which returns a shared no-op context) against
+the pinned budget below and exits non-zero when either is blown — CI
+runs this on every change. The profiler (repro.prof) sits on the same
+hot path, so the gate also pins its detached site (attribute read +
+branch) and the amortized cost of sampled profiling at the default
+1-in-16 period.
 """
 
 from __future__ import annotations
@@ -59,8 +61,8 @@ def _site_cost_ns(stmt: str, setup: str, number: int = 200_000,
 
 
 def measure() -> dict[str, float]:
-    """Per-site instrumentation costs (ns): disabled branch, enabled
-    counter inc, and the bare-loop floor for context."""
+    """Per-site instrumentation costs (ns): disabled branch, disabled
+    span, enabled counter inc, and the bare-loop floor for context."""
     base = ("from repro.obs import runtime as obs\n"
             "from repro.obs.metrics import MetricsRegistry\n")
     disabled = _site_cost_ns(
@@ -73,9 +75,13 @@ def measure() -> dict[str, float]:
         "if m is not None:\n"
         "    m.counter('launch.count', kernel='k').inc()",
         base + "obs.disable(); obs.enable(trace=False)")
+    disabled_span = _site_cost_ns(
+        "with obs.span('launch.select', cat='kernel'):\n"
+        "    pass",
+        base + "obs.disable()")
     floor = _site_cost_ns("pass", base)
-    out = {"disabled_site_ns": disabled, "enabled_site_ns": enabled,
-           "loop_floor_ns": floor}
+    out = {"disabled_site_ns": disabled, "disabled_span_ns": disabled_span,
+           "enabled_site_ns": enabled, "loop_floor_ns": floor}
     out.update(measure_prof())
     return out
 
@@ -118,6 +124,8 @@ def check() -> int:
     costs = measure()
     print(f"disabled instrument site: {costs['disabled_site_ns']:.1f} ns "
           f"(budget {DISABLED_SITE_BUDGET_NS:.0f} ns)")
+    print(f"disabled span site:       {costs['disabled_span_ns']:.1f} ns "
+          f"(budget {DISABLED_SITE_BUDGET_NS:.0f} ns)")
     print(f"enabled counter inc:      {costs['enabled_site_ns']:.1f} ns "
           f"(budget {ENABLED_SITE_BUDGET_NS:.0f} ns)")
     print(f"detached profiler site:   "
@@ -130,6 +138,8 @@ def check() -> int:
     failures = []
     if costs["disabled_site_ns"] > DISABLED_SITE_BUDGET_NS:
         failures.append("disabled-site budget blown")
+    if costs["disabled_span_ns"] > DISABLED_SITE_BUDGET_NS:
+        failures.append("disabled-span budget blown")
     if costs["enabled_site_ns"] > ENABLED_SITE_BUDGET_NS:
         failures.append("enabled-site budget blown")
     if costs["prof_disabled_site_ns"] > PROF_DISABLED_SITE_BUDGET_NS:

@@ -3,7 +3,8 @@
 The paper caches NVRTC-compiled kernels per (kernel, problem size); we cache
 AOT-compiled XLA executables per (kernel, device, problem, dtype, config).
 Timings of the miss path are split the same way Fig 5 splits them:
-wisdom read / compile / load / launch.
+wisdom read / compile / launch, the launch split again into the dispatch
+and the wait for the device.
 
 That cache lives in one process. :func:`enable_persistent_cache` turns on
 JAX's on-disk compilation cache as well, so a second process on the same
@@ -54,10 +55,16 @@ class LaunchStats:
     wisdom_read_s: float = 0.0
     select_s: float = 0.0
     compile_s: float = 0.0     # trace+lower+compile ("NVRTC" analogue)
-    load_s: float = 0.0        # executable construction ("cuModuleLoad")
-    launch_s: float = 0.0      # dispatch + wait ("cuLaunchKernel")
+    dispatch_s: float = 0.0    # argument wrap + executable call, which
+    #                            returns before the device finishes
+    wait_s: float = 0.0        # block_until_ready on the result
     tier: str = ""
     config: dict = field(default_factory=dict)
+
+    @property
+    def launch_s(self) -> float:
+        """Dispatch plus wait ("cuLaunchKernel" and the sync after it)."""
+        return self.dispatch_s + self.wait_s
 
 
 class CompileCache:

@@ -18,6 +18,13 @@ selection tier, a small epsilon fraction of launches runs a candidate
 config instead of the incumbent ("trial" tier), and confident winners are
 promoted into the wisdom file live. Traced launches never participate —
 the outer jit owns those.
+
+With tracing on (``repro.obs``), each eager launch is one ``launch`` span
+whose children are its phases: ``launch.select``, ``launch.build``,
+``launch.compile`` (cache misses only), ``launch.dispatch`` and
+``launch.wait``; the parent's time outside them is the preparation
+(argument metadata, problem size, backend, capture check) and the
+bookkeeping after the wait.
 """
 
 from __future__ import annotations
@@ -142,7 +149,7 @@ class WisdomKernel:
                self.builder.space.freeze(config))
         fn = self._instantiate(config, meta, backend)
         _, _, cached = self.compile_cache.get_or_compile(
-            key, lambda: jax.jit(fn).lower(*meta).compile())
+            key, lambda: self._compile(fn, meta))
         return not cached
 
     def select_config(self, problem: tuple[int, ...], dtype: str
@@ -183,59 +190,93 @@ class WisdomKernel:
     # -- launch ---------------------------------------------------------------
 
     def __call__(self, *args, config: Config | None = None):
+        if any(isinstance(a, jax.core.Tracer) for a in args):
+            return self._call_traced(args, config)
+        with obs.span("launch", cat="kernel") as span:
+            return self._launch(args, config, span)
+
+    def _call_traced(self, args, config: Config | None):
+        """Inside an outer trace: select from the static shapes and inline
+        the built kernel; the outer jit owns compilation. Online tuning
+        still sees the (trace-time) selection so demand from jitted
+        launch streams is tracked; tuning work for it runs via
+        ``OnlineTuner.tick()``, not launch hooks."""
         meta = args_meta(*args)
         problem = self.builder.get_problem_size(*meta)
         dtype = self.builder.get_dtype(*meta)
-        backend = resolve_backend(self._backend)
-
-        traced = any(isinstance(a, jax.core.Tracer) for a in args)
-        if not traced and capture_requested(self.builder.name):
-            write_capture(self.builder.name, problem, dtype, args,
-                          extra_meta={"device_kind": self.device_kind,
-                                      "source": self.builder.source})
-
-        t_sel0 = time.perf_counter()
         if config is None:
             config, tier = self.select_config(problem, dtype)
         else:
             tier = "forced"
+        self._observe_selection(problem, dtype, tier)
+        fn = self._instantiate(config, meta, resolve_backend(self._backend))
+        if self.online is not None and tier != "forced":
+            self.online.observe_traced(problem, dtype, meta, config, tier)
+        return fn(*args)
+
+    def _launch(self, args, config: Config | None, span: dict | None):
+        """One eager launch, phase by phase: prepare, ``launch.select``,
+        ``launch.build``, ``launch.compile`` (a cache miss only),
+        ``launch.dispatch`` (the executable returns before the device
+        finishes) and ``launch.wait``. ``span`` is the enclosing
+        ``launch`` span's args (None with tracing off): the kernel and
+        scenario as soon as they are known, the rest once the launch has
+        finished."""
+        meta = args_meta(*args)
+        problem = self.builder.get_problem_size(*meta)
+        dtype = self.builder.get_dtype(*meta)
+        if span is not None:
+            span.update(kernel=self.builder.name,
+                        scenario=format_key((self.device_kind, problem,
+                                             dtype)))
+        backend = resolve_backend(self._backend)
+        if capture_requested(self.builder.name):
+            write_capture(self.builder.name, problem, dtype, args,
+                          extra_meta={"device_kind": self.device_kind,
+                                      "source": self.builder.source})
+
         online = self.online
-        if online is not None and not traced and tier != "forced":
-            trial = online.before_launch(problem, dtype, meta, config, tier)
-            if trial is not None:
-                config, tier = dict(trial), "trial"
-        select_s = time.perf_counter() - t_sel0
+        with obs.span("launch.select", cat="kernel"):
+            t0 = time.perf_counter()
+            if config is None:
+                config, tier = self.select_config(problem, dtype)
+            else:
+                tier = "forced"
+            if online is not None and tier != "forced":
+                trial = online.before_launch(problem, dtype, meta, config,
+                                             tier)
+                if trial is not None:
+                    config, tier = dict(trial), "trial"
+            select_s = time.perf_counter() - t0
         self._observe_selection(problem, dtype, tier)
 
-        fn = self._instantiate(config, meta, backend)
+        with obs.span("launch.build", cat="kernel"):
+            fn = self._instantiate(config, meta, backend)
+            key = (self.device_kind, backend, problem, dtype,
+                   self.builder.space.freeze(config))
 
-        if traced:
-            # Inside an outer trace: inline; the outer jit owns compilation.
-            # Online tuning still gets to see the (trace-time) selection so
-            # demand from jitted launch streams is tracked; tuning work for
-            # it runs via OnlineTuner.tick(), not launch hooks.
-            if online is not None and tier != "forced":
-                online.observe_traced(problem, dtype, meta, config, tier)
-            return fn(*args)
-
-        key = (self.device_kind, backend, problem, dtype,
-               self.builder.space.freeze(config))
-
-        def _compile() -> Callable:
-            return jax.jit(fn).lower(*meta).compile()
+        def compile_() -> Callable:
+            with obs.span("launch.compile", cat="kernel"):
+                return self._compile(fn, meta)
 
         compiled, compile_s, cached = self.compile_cache.get_or_compile(
-            key, _compile)
-        t0 = time.perf_counter()
-        out = compiled(*[np.asarray(a) if not hasattr(a, "dtype") else a
-                         for a in args])
-        out = jax.block_until_ready(out)
-        launch_s = time.perf_counter() - t0
-        self.stats.append(LaunchStats(
+            key, compile_)
+
+        with obs.span("launch.dispatch", cat="kernel"):
+            t0 = time.perf_counter()
+            out = compiled(*[np.asarray(a) if not hasattr(a, "dtype") else a
+                             for a in args])
+            t1 = time.perf_counter()
+        with obs.span("launch.wait", cat="kernel"):
+            out = jax.block_until_ready(out)
+            t2 = time.perf_counter()
+        st = LaunchStats(
             kernel=self.builder.name, cached=cached,
             wisdom_read_s=0.0 if cached else self._wisdom_read_s,
-            select_s=select_s, compile_s=compile_s, launch_s=launch_s,
-            tier=tier, config=dict(config)))
+            select_s=select_s, compile_s=compile_s, dispatch_s=t1 - t0,
+            wait_s=t2 - t1, tier=tier, config=dict(config))
+        self.stats.append(st)
+        launch_s = st.launch_s
         m = obs.metrics()
         if m is not None:
             name = self.builder.name
@@ -249,23 +290,10 @@ class WisdomKernel:
             if not cached:
                 m.histogram("compile.latency_us",
                             kernel=name).observe(compile_s * 1e6)
-        tr = obs.tracer()
-        if tr is not None:
-            # Record the finished launch as one complete event (the work
-            # already happened; re-running it under a context manager
-            # would distort the hot path). ts/dur reconstruct the span.
-            t_end = tr._now_us()
-            dur = round((select_s + compile_s + launch_s) * 1e6, 3)
-            tr.events.append({
-                "name": "launch", "cat": "kernel", "ph": "X",
-                "ts": round(t_end - dur, 3), "dur": dur,
-                "pid": tr.pid, "tid": tr._tid(),
-                "args": {"kernel": self.builder.name, "tier": tier,
-                         "scenario": format_key((self.device_kind,
-                                                 problem, dtype)),
-                         "cached": cached,
-                         "compile_us": round(compile_s * 1e6, 3),
-                         "launch_us": round(launch_s * 1e6, 3)}})
+        if span is not None:
+            span.update(tier=tier, cached=cached,
+                        compile_us=round(compile_s * 1e6, 3),
+                        launch_us=round(launch_s * 1e6, 3))
         profiler = self.profiler
         if profiler is not None and profiler.due(self.builder.name):
             profiler.profile_launch(
@@ -276,6 +304,14 @@ class WisdomKernel:
         if online is not None:
             online.after_launch(problem, dtype, config, tier, launch_s)
         return out
+
+    def _compile(self, fn: Callable, meta) -> Callable:
+        """AOT-compile ``fn`` as a program named after the kernel, so a
+        device trace shows module ``jit_<kernel>`` for its launches."""
+        def named(*args):
+            return fn(*args)
+        named.__name__ = named.__qualname__ = self.builder.name
+        return jax.jit(named).lower(*meta).compile()
 
     def _instantiate(self, config: Config, meta, backend: str) -> Callable:
         if backend == "reference":

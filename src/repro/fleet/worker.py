@@ -129,14 +129,10 @@ class FleetWorker:
                                     self.worker_id, self.clock, self.ttl_s)
                 if lease is None:
                     continue
-                tr = obs.tracer()
                 try:
-                    if tr is not None:
-                        with tr.span("fleet.shard", cat="fleet",
-                                     job=job.job_id, shard=shard_id,
-                                     worker=self.worker_id):
-                            self._run_shard(job, shard_id, lease)
-                    else:
+                    with obs.span("fleet.shard", cat="fleet",
+                                  job=job.job_id, shard=shard_id,
+                                  worker=self.worker_id):
                         self._run_shard(job, shard_id, lease)
                 except LeaseLost:
                     continue            # reclaimed under us: theirs now

@@ -11,7 +11,8 @@ two primitives and a report:
   ``trace_event`` JSON (open in chrome://tracing or Perfetto);
 * :mod:`.runtime` — the on/off switch: disabled (default) costs one
   global read + branch per instrument site, enabled via
-  :func:`enable` or ``KERNEL_LAUNCHER_OBS=1``;
+  :func:`enable` or ``KERNEL_LAUNCHER_OBS=1``; :func:`span` is the span
+  site, and ``enable(profiler=True)`` sends spans to the JAX profiler;
 * :mod:`.report`  — the wisdom-health report (hit rates, tier breakdown,
   transfer confidence, top missing scenarios) rendered deterministically
   from a snapshot or a saved trace;
@@ -30,7 +31,7 @@ from .metrics import (COUNT_BUCKETS, DEFAULT_BUCKETS_US, SNAPSHOT_VERSION,
 from .report import (ScenarioHealth, fleet_report, render_report,
                      scenario_health, snapshot_from_trace)
 from .runtime import (OBS_ENV, disable, enable, enabled, metrics,
-                      obs_requested, tracer)
+                      obs_requested, span, tracer)
 from .trace import (REQUIRED_EVENT_KEYS, Tracer, load_trace,
                     validate_trace)
 
@@ -42,6 +43,6 @@ __all__ = [
     "ScenarioHealth", "fleet_report", "render_report", "scenario_health",
     "snapshot_from_trace",
     "OBS_ENV", "disable", "enable", "enabled", "metrics", "obs_requested",
-    "tracer",
+    "span", "tracer",
     "REQUIRED_EVENT_KEYS", "Tracer", "load_trace", "validate_trace",
 ]

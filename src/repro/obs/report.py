@@ -86,15 +86,16 @@ def snapshot_from_trace(trace: dict) -> dict:
 
     ``launch`` spans carry kernel/scenario/tier in their args; each one
     becomes a ``select.tier`` increment, and span durations rebuild the
-    per-kernel launch-latency histograms. A trace is therefore an
+    per-kernel launch-latency histograms. A launch that raised (its span
+    carries an ``error`` arg) is skipped. A trace is therefore an
     alternative — replayable — source for the same health report.
     """
     from .metrics import MetricsRegistry
     reg = MetricsRegistry()
     for ev in trace.get("traceEvents", []):
-        if ev.get("name") != "launch":
-            continue
         args = ev.get("args", {})
+        if ev.get("name") != "launch" or "error" in args:
+            continue
         kernel = str(args.get("kernel", "?"))
         tier = str(args.get("tier", "?"))
         scenario = str(args.get("scenario", "?"))

@@ -14,8 +14,13 @@ it: production uses ``time.perf_counter``, tests drive a manual clock so
 exported traces are byte-deterministic. Thread ids are mapped to small
 dense ints in first-seen order for the same reason.
 
+That clock is the tracer's own. With ``profiler`` set every span is
+also entered as a ``jax.profiler.TraceAnnotation``: while a JAX profiler
+session records, the span lands in its host plane under its bare name,
+on the same clock as the device's operations, with its args as metadata.
+
 The disabled path never reaches this module — ``repro.obs.runtime`` hands
-instrument sites ``None`` instead of a tracer.
+instrument sites ``None`` (or a shared no-op span) instead of a tracer.
 """
 
 from __future__ import annotations
@@ -23,7 +28,6 @@ from __future__ import annotations
 import json
 import threading
 import time
-from contextlib import contextmanager
 from pathlib import Path
 from typing import Callable
 
@@ -48,8 +52,22 @@ class Tracer:
         self._clock = clock or time.perf_counter
         self._epoch = self._clock()
         self.pid = int(pid)
+        self._annotate = None
         self.events: list[dict] = []
         self._tids: dict[int, int] = {}
+
+    @property
+    def profiler(self) -> bool:
+        """Whether each span is also entered as a
+        ``jax.profiler.TraceAnnotation`` (the profiler sink)."""
+        return self._annotate is not None
+
+    @profiler.setter
+    def profiler(self, on: bool) -> None:
+        self._annotate = None
+        if on:
+            from jax.profiler import TraceAnnotation
+            self._annotate = TraceAnnotation
 
     def _now_us(self) -> float:
         return round((self._clock() - self._epoch) * 1e6, 3)
@@ -61,21 +79,13 @@ class Tracer:
             tid = self._tids[ident] = len(self._tids)
         return tid
 
-    @contextmanager
-    def span(self, name: str, cat: str = "repro", **args):
+    def span(self, name: str, cat: str = "repro", **args) -> "_Span":
         """Record one complete event around the enclosed work. ``args``
-        become the event's ``args`` dict (JSON-safe values only)."""
-        t0 = self._now_us()
-        try:
-            yield self
-        finally:
-            t1 = self._now_us()
-            self.events.append({
-                "name": name, "cat": cat, "ph": "X",
-                "ts": t0, "dur": round(t1 - t0, 3),
-                "pid": self.pid, "tid": self._tid(),
-                "args": {k: v for k, v in sorted(args.items())},
-            })
+        become the event's ``args`` dict (JSON-safe values only); the
+        dict is what the ``with`` statement binds, so the body can add
+        args that are known only at its end. A span left by an exception
+        gets an ``error`` arg, the exception's type name."""
+        return _Span(self, name, cat, args)
 
     def instant(self, name: str, cat: str = "repro", **args) -> None:
         """Record a zero-duration marker (promotions, sync failures)."""
@@ -122,6 +132,41 @@ class Tracer:
 
     def __len__(self) -> int:
         return len(self.events)
+
+
+class _Span:
+    """One span of a :class:`Tracer`: a plain class, not a generator
+    context manager, so that a span costs few interpreter calls."""
+
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_annotation")
+
+    def __init__(self, tracer: Tracer, name: str, cat: str, args: dict):
+        self._tracer, self._name, self._cat, self._args = (tracer, name,
+                                                           cat, args)
+        self._annotation = None
+
+    def __enter__(self) -> dict:
+        tr = self._tracer
+        if tr._annotate is not None:
+            self._annotation = tr._annotate(self._name)
+            self._annotation.__enter__()
+        self._t0 = tr._now_us()
+        return self._args
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        tr, args = self._tracer, self._args
+        t1 = tr._now_us()
+        if exc_type is not None:
+            args["error"] = exc_type.__name__
+        if self._annotation is not None:
+            if args:
+                self._annotation.set_metadata(**args)
+            self._annotation.__exit__(None, None, None)
+        tr.events.append({
+            "name": self._name, "cat": self._cat, "ph": "X",
+            "ts": self._t0, "dur": round(t1 - self._t0, 3),
+            "pid": tr.pid, "tid": tr._tid(),
+            "args": dict(sorted(args.items()))})
 
 
 def load_trace(path: Path | str) -> dict:

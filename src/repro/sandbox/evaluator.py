@@ -316,12 +316,8 @@ class SandboxedEvaluator:
             # reduced, picklable payload (info can hold Workloads, which
             # must not cross the fork pipe)
             return (r.score_us, r.feasible, r.verified, r.error)
-        tr = obs.tracer()
-        if tr is not None:
-            with tr.span("sandbox.eval", cat="sandbox",
-                         method=self.settings.method):
-                verdict, payload = sandboxed_call(run, self.settings)
-        else:
+        with obs.span("sandbox.eval", cat="sandbox",
+                      method=self.settings.method):
             verdict, payload = sandboxed_call(run, self.settings)
         self.verdicts.append((dict(config), verdict))
         self._observe(verdict)

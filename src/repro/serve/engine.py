@@ -26,6 +26,11 @@ Two scheduling modes over the same static (n_slots, max_seq) KV arena
   occupancy loss ``benchmarks/serve_throughput.py`` measures.
 
 Greedy (argmax) or temperature sampling.
+
+With tracing on (``repro.obs``) every decode step is one ``serve.step``
+span whose children are its phases: ``serve.admit`` (token mode),
+``serve.dispatch``, ``serve.wait`` (steps the profiler samples),
+``serve.tick``, ``serve.sample`` and ``serve.advance``.
 """
 
 from __future__ import annotations
@@ -211,40 +216,53 @@ class ServeEngine:
         return np.array([self._rng.choice(p.shape[-1], p=pi)
                          for pi in p], np.int32)
 
-    def _decode_once(self, cache, next_tok):
-        """One jitted decode step, profiler-sampled when due."""
+    def _decode_once(self, cache, next_tok, starts=None):
+        """One jitted decode step: the host uploads (``starts`` becomes
+        ``cache["start"]``) and the call under ``serve.dispatch``, which
+        returns before the device finishes. A step the profiler samples
+        also waits for the logits (``serve.wait``) and reports its time
+        to the profiler."""
         prof = self.profiler
-        if prof is not None and prof.due(self.steps_run):
-            # Sampled step: time to a blocking boundary. Only these
-            # steps pay the extra sync; the rest overlap as before.
-            t0 = time.perf_counter()
+        sampled = prof is not None and prof.due(self.steps_run)
+        t0 = time.perf_counter()
+        with obs.span("serve.dispatch", cat="serve"):
+            if starts is not None:
+                cache["start"] = jnp.asarray(starts)
             logits, cache = self._decode(self.params, cache,
                                          jnp.asarray(next_tok))
-            logits = jax.block_until_ready(logits)
+        if sampled:
+            # Only sampled steps pay the extra sync; the rest overlap
+            # the device's work with the host's until the logits pull.
+            with obs.span("serve.wait", cat="serve"):
+                logits = jax.block_until_ready(logits)
             prof.on_step((time.perf_counter() - t0) * 1e6)
-        else:
-            logits, cache = self._decode(self.params, cache,
-                                         jnp.asarray(next_tok))
         self.steps_run += 1
         return logits, cache
 
+    def _sample_logits(self, logits) -> np.ndarray:
+        """Pull the last position's logits to the host (waiting for the
+        device if no sampled step waited already) and sample."""
+        with obs.span("serve.sample", cat="serve"):
+            return self._sample(np.asarray(logits[:, 0]))
+
     def _tick_services(self, m) -> None:
         """Per-decode-step collaborator ticks (both modes)."""
-        if m is not None:
-            m.counter("serve.decode_steps").inc()
-        for svc in self.online:
-            svc.tick()
-        if self.sync is not None:
-            fails_before = self.sync.failures
-            pulled = self.sync.tick()
+        with obs.span("serve.tick", cat="serve"):
             if m is not None:
-                if pulled is not None:
-                    outcome = "pulled"
-                elif self.sync.failures > fails_before:
-                    outcome = "failed"
-                else:
-                    outcome = "skipped"
-                m.counter("serve.sync_tick", outcome=outcome).inc()
+                m.counter("serve.decode_steps").inc()
+            for svc in self.online:
+                svc.tick()
+            if self.sync is not None:
+                fails_before = self.sync.failures
+                pulled = self.sync.tick()
+                if m is not None:
+                    if pulled is not None:
+                        outcome = "pulled"
+                    elif self.sync.failures > fails_before:
+                        outcome = "failed"
+                    else:
+                        outcome = "skipped"
+                    m.counter("serve.sync_tick", outcome=outcome).inc()
 
     # -- cohort mode ---------------------------------------------------------
 
@@ -259,25 +277,27 @@ class ServeEngine:
             next_tok[slot, 0] = req.prompt[0]
         t = 0
         while not all(done.values()) and t < self.max_seq - 1:
-            m = obs.metrics()
-            live = sum(1 for v in done.values() if not v)
-            self._useful_slot_steps += live
-            if m is not None:
-                m.histogram("batch.occupancy",
-                            UNIT_BUCKETS).observe(live / self.n_slots)
-            logits, cache = self._decode_once(cache, next_tok)
-            self._tick_services(m)
-            sampled = self._sample(np.asarray(logits[:, 0]))
-            for slot, req in reqs.items():
-                if done[slot]:
-                    continue
-                if t + 1 < len(req.prompt):
-                    next_tok[slot, 0] = req.prompt[t + 1]   # still feeding
-                else:
-                    req.tokens.append(int(sampled[slot]))
-                    next_tok[slot, 0] = sampled[slot]
-                    if len(req.tokens) >= req.max_new_tokens:
-                        done[slot] = True
+            with obs.span("serve.step", cat="serve"):
+                m = obs.metrics()
+                live = sum(1 for v in done.values() if not v)
+                self._useful_slot_steps += live
+                if m is not None:
+                    m.histogram("batch.occupancy",
+                                UNIT_BUCKETS).observe(live / self.n_slots)
+                logits, cache = self._decode_once(cache, next_tok)
+                self._tick_services(m)
+                sampled = self._sample_logits(logits)
+                with obs.span("serve.advance", cat="serve"):
+                    for slot, req in reqs.items():
+                        if done[slot]:
+                            continue
+                        if t + 1 < len(req.prompt):
+                            next_tok[slot, 0] = req.prompt[t + 1]  # feeding
+                        else:
+                            req.tokens.append(int(sampled[slot]))
+                            next_tok[slot, 0] = sampled[slot]
+                            if len(req.tokens) >= req.max_new_tokens:
+                                done[slot] = True
             t += 1
         # release slots
         for slot, rid, _ in members:
@@ -302,17 +322,35 @@ class ServeEngine:
                 m.histogram("serve.cohort_size",
                             COUNT_BUCKETS).observe(len(members))
                 m.gauge("serve.queue_depth").set(self.batcher.queue_depth)
-            tr = obs.tracer()
-            if tr is not None:
-                with tr.span("serve.cohort", cat="serve",
-                             cohort=cohorts, size=len(members)):
-                    self._run_cohort(members)
-            else:
+            with obs.span("serve.cohort", cat="serve", cohort=cohorts,
+                          size=len(members)):
                 self._run_cohort(members)
             cohorts += 1
         return cohorts
 
     # -- token mode ----------------------------------------------------------
+
+    def _admit(self, arena_pos: int, starts: np.ndarray, fed: list[int],
+               next_tok: np.ndarray) -> int:
+        """Admit queued requests into free slots at the arena cursor and
+        queue each one's first prompt token; returns how many."""
+        b = self.batcher
+        with obs.span("serve.admit", cat="serve") as span:
+            active_before = b.active_slots
+            admitted = b.admit(arena_pos=arena_pos)
+            for slot, rid, _plen in admitted:
+                next_tok[slot, 0] = self._requests[rid].prompt[0]
+                starts[slot] = arena_pos
+                fed[slot] = 1
+            if admitted:
+                if active_before > 0:
+                    self._inflight_admissions += len(admitted)
+                m = obs.metrics()
+                if m is not None:
+                    m.gauge("serve.queue_depth").set(b.queue_depth)
+            if span is not None:
+                span["requests"] = [rid for _, rid, _ in admitted]
+        return len(admitted)
 
     def _run_arena(self) -> None:
         """One arena generation: fresh cache, write cursor at 0, then
@@ -326,54 +364,45 @@ class ServeEngine:
         next_tok = np.zeros((self.n_slots, 1), np.int32)
         arena_pos = 0
         while arena_pos < self.max_seq:
-            m = obs.metrics()
-            active_before = b.active_slots
-            admitted = b.admit(arena_pos=arena_pos)
-            for slot, rid, _plen in admitted:
-                req = self._requests[rid]
-                next_tok[slot, 0] = req.prompt[0]
-                starts[slot] = arena_pos
-                fed[slot] = 1
-            if admitted and active_before > 0:
-                self._inflight_admissions += len(admitted)
-            if admitted and m is not None:
-                m.gauge("serve.queue_depth").set(b.queue_depth)
-            active = [i for i, s in enumerate(b.slots) if s.active]
-            if not active:
+            # With every slot free, only an admission makes a step: it
+            # runs before the step, which then exists only if it admitted.
+            idle = not b.active_slots
+            if idle and not self._admit(arena_pos, starts, fed, next_tok):
                 break       # drained, or head request needs a fresh arena
-            self._useful_slot_steps += len(active)
-            if m is not None:
-                m.histogram("batch.occupancy",
-                            UNIT_BUCKETS).observe(len(active)
-                                                  / self.n_slots)
-            cache["start"] = jnp.asarray(starts)
-            logits, cache = self._decode_once(cache, next_tok)
-            arena_pos += 1
-            self._tick_services(m)
-            sampled = self._sample(np.asarray(logits[:, 0]))
-            completed = 0
-            for i in active:
-                req = self._requests[b.slots[i].request_id]
-                if fed[i] < len(req.prompt):
-                    next_tok[i, 0] = req.prompt[fed[i]]     # still feeding
-                    fed[i] += 1
-                    continue
-                req.tokens.append(int(sampled[i]))
-                next_tok[i, 0] = sampled[i]
-                if b.advance(i) is not None:
-                    completed += 1          # slot freed; refilled next step
-            if completed and m is not None:
-                m.counter("serve.requests_completed").inc(completed)
+            with obs.span("serve.step", cat="serve"):
+                if not idle:
+                    self._admit(arena_pos, starts, fed, next_tok)
+                m = obs.metrics()
+                active = [i for i, s in enumerate(b.slots) if s.active]
+                self._useful_slot_steps += len(active)
+                if m is not None:
+                    m.histogram("batch.occupancy",
+                                UNIT_BUCKETS).observe(len(active)
+                                                      / self.n_slots)
+                logits, cache = self._decode_once(cache, next_tok, starts)
+                arena_pos += 1
+                self._tick_services(m)
+                sampled = self._sample_logits(logits)
+                with obs.span("serve.advance", cat="serve"):
+                    completed = 0
+                    for i in active:
+                        req = self._requests[b.slots[i].request_id]
+                        if fed[i] < len(req.prompt):
+                            next_tok[i, 0] = req.prompt[fed[i]]  # feeding
+                            fed[i] += 1
+                            continue
+                        req.tokens.append(int(sampled[i]))
+                        next_tok[i, 0] = sampled[i]
+                        if b.advance(i) is not None:
+                            completed += 1  # slot freed; refilled next step
+                    if completed and m is not None:
+                        m.counter("serve.requests_completed").inc(completed)
 
     def _run_token_mode(self, max_generations: int) -> int:
         generations = 0
         while generations < max_generations and not self.batcher.done():
-            tr = obs.tracer()
-            if tr is not None:
-                with tr.span("serve.arena", cat="serve",
-                             generation=generations):
-                    self._run_arena()
-            else:
+            with obs.span("serve.arena", cat="serve",
+                          generation=generations):
                 self._run_arena()
             generations += 1
         return generations
