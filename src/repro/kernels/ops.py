@@ -9,12 +9,14 @@ instead. Model code only ever calls these functions.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import WisdomKernel, resolve_backend
 from repro.core.device import current_device
 
 from . import advec_u as _advec_mod
+from . import decode_attention as _da_mod
 from . import diff_uvw as _diff_mod
 from . import flash_attention as _fa_mod
 from . import matmul as _mm_mod
@@ -25,9 +27,10 @@ diff_uvw_kernel = WisdomKernel(_diff_mod.builder)
 matmul_kernel = WisdomKernel(_mm_mod.builder)
 fa_causal_kernel = WisdomKernel(_fa_mod.causal_builder)
 fa_full_kernel = WisdomKernel(_fa_mod.full_builder)
+decode_attention_kernel = WisdomKernel(_da_mod.builder)
 
 _ALL_KERNELS = (advec_u_kernel, diff_uvw_kernel, matmul_kernel,
-                fa_causal_kernel, fa_full_kernel)
+                fa_causal_kernel, fa_full_kernel, decode_attention_kernel)
 
 
 def reload_wisdom() -> None:
@@ -108,3 +111,63 @@ def attention(q, k, v, *, causal: bool = True, window: int | None = None,
     kernel = fa_causal_kernel if causal else fa_full_kernel
     of = kernel(qf, kf, vf)
     return of.reshape(B, Hq, Sq, D)
+
+
+#: Always-on tally of the route every :func:`decode_attention` call took:
+#: ``"pallas"``, or ``"reference:<reason>"`` for the jnp oracle; once
+#: per trace under an outer ``jax.jit``, like :data:`attention_routes`.
+decode_attention_routes: dict[str, int] = {}
+
+
+def _decode_attention_route(S: int) -> str:
+    if resolve_backend() not in ("pallas", "interpret"):
+        return "reference:backend"
+    if current_device().backend == "gpu":
+        return "reference:gpu"
+    if S % 128:
+        return "reference:shape"
+    return "pallas"
+
+
+def decode_attention(q, cache_k, cache_v, k_new, v_new, layer, pos, *,
+                     window=0, softcap: float | None = None, start=None):
+    """One query token per slot against layer ``layer`` of the KV arena.
+
+    q: (B, Hq, 1, D); cache_k/v: the whole stacked arena
+    (L, B, Hkv, D, S), read where it lies; k_new/v_new: (B, Hkv, D), the
+    step's own K/V, attended at ``pos`` though the arena does not hold
+    them yet (the caller writes them after the step). ``window`` may be
+    traced (0 = unlimited); ``start`` (B,) scopes slot b to positions
+    [start[b], pos]. Routes to the Pallas kernel where the backend and
+    the arena's length allow, else to the jnp oracle
+    (:func:`repro.kernels.ref.decode_attention_ref`); each call's route
+    is tallied in :data:`decode_attention_routes`.
+    """
+    route = _decode_attention_route(cache_k.shape[-1])
+    decode_attention_routes[route] = decode_attention_routes.get(route, 0) + 1
+    ints = jnp.stack([jnp.asarray(x, jnp.int32) for x in (layer, pos, window)])
+    cap = jnp.full((1,), softcap or 0.0, jnp.float32)
+    if route != "pallas":
+        return _da_mod.decode_attention_ref(cache_k, cache_v, q, k_new, v_new,
+                                            ints, start, cap)
+    if start is None:
+        start = jnp.zeros((q.shape[0],), jnp.int32)
+    return decode_attention_kernel(cache_k, cache_v, q, k_new, v_new, ints,
+                                   start, cap)
+
+
+def write_kv_position(k_arena, v_arena, k, v, pos):
+    """Write the step's K/V of every layer, k/v (L, B, Hkv, D), into the
+    arena (L, B, Hkv, D, S) at ``pos``; returns the new (k_arena,
+    v_arena). Takes the route :func:`decode_attention` takes for this
+    arena: the in-place Pallas write
+    (:func:`repro.kernels.decode_attention.write_position`) where it
+    runs the kernel, else ``dynamic_update_slice``."""
+    route = _decode_attention_route(k_arena.shape[-1])
+    if route == "pallas":
+        return _da_mod.write_position(
+            k_arena, v_arena, k, v, pos,
+            interpret=resolve_backend() == "interpret")
+    return tuple(jax.lax.dynamic_update_slice_in_dim(
+        arena, new[..., None].astype(arena.dtype), pos, axis=4)
+        for arena, new in ((k_arena, k), (v_arena, v)))

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 
 # --------------------------------------------------------------------------
@@ -268,3 +269,55 @@ def flash_attention_ref_factory(causal: bool):
         return o[:, 0]
 
     return ref
+
+
+# --------------------------------------------------------------------------
+# decode attention (one query token per slot against the KV arena)
+# --------------------------------------------------------------------------
+
+
+def cap_scores(s, cap):
+    """Softcap with the cap as data: ``cap * tanh(s / cap)`` where
+    ``cap > 0``, ``s`` unchanged where it is 0."""
+    on = cap > 0
+    return jnp.where(on, cap * jnp.tanh(s / jnp.where(on, cap, 1.0)), s)
+
+
+def decode_attention_ref(q, cache_k, cache_v, pos, window, softcap=None,
+                         start=None):
+    """q: (B, Hq, 1, D) against one layer's cache, cache_k/v
+    (B, Hkv, D, S) (the arena's layout, sequence axis minor) that already
+    holds the step's K/V at ``pos``. Masks unwritten and out-of-window
+    positions, plus per-batch positions below ``start`` (stale cache
+    from a slot's previous occupant). ``window`` and ``softcap`` may be
+    traced (0 = unlimited / none). Masking (not zeroing) is load-bearing
+    for slot reuse: a zeroed K row still gets softmax weight exp(0), so
+    stale rows must be excluded from the normalization, and rotary
+    phases stay correct because only relative distances inside
+    [start, pos] survive.
+
+    The cache stays in its storage dtype — an ``astype(f32)`` here gets
+    hoisted by the compiler into a full f32 copy of the *whole stacked
+    cache* (2x the serving HBM); f32 accumulation comes from
+    ``preferred_element_type`` instead."""
+    B, Hq, _, D = q.shape
+    Hkv = cache_k.shape[1]
+    group = Hq // Hkv
+    qg = q.reshape(B, Hkv, group, D).astype(cache_k.dtype)
+    s = jnp.einsum("bhgd,bhdk->bhgk", qg, cache_k,
+                   preferred_element_type=jnp.float32) * (D ** -0.5)
+    if softcap is not None:
+        s = cap_scores(s, jnp.asarray(softcap, jnp.float32))
+    k_pos = jnp.arange(cache_k.shape[3])
+    valid = k_pos <= pos
+    valid &= jnp.where(window > 0, (pos - k_pos) < window, True)
+    if start is None:
+        mask = valid[None, None, None, :]
+    else:
+        mask = (valid[None, :]
+                & (k_pos[None, :] >= start[:, None]))[:, None, None, :]
+    s = jnp.where(mask, s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhgk,bhdk->bhgd", p.astype(cache_v.dtype), cache_v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(B, Hq, 1, D).astype(q.dtype)

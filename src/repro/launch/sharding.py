@@ -195,12 +195,13 @@ def _cache_spec(path_names, leaf, mesh) -> P:
     if nd == 0:
         return P()
     if name in ("k", "v"):
-        # (L, B, H, S, D) (kv / cross_kv stacks)
+        # kv arenas (L, B, H, D, S); cross_kv stacks (L, B, H, M, D)
+        seq = 3 if "cross_kv" in path_names else 4
         assign = {1: dp}
         if _fit(mesh, shape[2], "model"):
             assign[2] = "model"
         else:
-            assign[3] = "model" if _fit(mesh, shape[1], dp) else \
+            assign[seq] = "model" if _fit(mesh, shape[1], dp) else \
                 ("data", "model")
         return _spec(mesh, shape, assign)
     if name in ("c_kv", "k_pe"):

@@ -4,7 +4,9 @@ Supports per-layer sliding windows *as data* (window scalar array; 0 = full
 attention) so heterogeneous stacks (gemma2 alternating, hymba's 3 global
 layers) run under one scanned layer body. Softcap per config. The underlying
 attention math routes through ``repro.kernels.ops.attention`` (Pallas flash
-kernel on TPU, oracle elsewhere).
+kernel on TPU, oracle elsewhere), the decode step's through
+``repro.kernels.ops.decode_attention`` (a Pallas kernel that reads the KV
+arena in place on TPU, oracle elsewhere).
 """
 
 from __future__ import annotations
@@ -90,13 +92,14 @@ def attn_forward(cfg: ArchConfig, p: dict, x: jax.Array,
 def attn_prefill(cfg: ArchConfig, p: dict, x: jax.Array, cache_k, cache_v,
                  window: jax.Array | int | None = None
                  ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Parallel prefill: forward + write K/V for positions [0, S) into the
-    cache. Returns (out, new_cache_k, new_cache_v)."""
+    """Parallel prefill: forward + write K/V for positions [0, S) into
+    one layer's cache (B, Hkv, D, max_seq). Returns (out, new_cache_k,
+    new_cache_v)."""
     out, k, v = _attn_core(cfg, p, x, window, causal=True)
     cache_k = lax.dynamic_update_slice_in_dim(
-        cache_k, k.astype(cache_k.dtype), 0, axis=2)
+        cache_k, k.swapaxes(2, 3).astype(cache_k.dtype), 0, axis=3)
     cache_v = lax.dynamic_update_slice_in_dim(
-        cache_v, v.astype(cache_v.dtype), 0, axis=2)
+        cache_v, v.swapaxes(2, 3).astype(cache_v.dtype), 0, axis=3)
     return out, cache_k, cache_v
 
 
@@ -112,74 +115,48 @@ def _masked_attention(q, k, v, window, causal: bool,
 
 def init_kv_cache(cfg: ArchConfig, n_layers: int, batch: int, max_seq: int,
                   dtype) -> dict:
-    shape = (n_layers, batch, cfg.n_kv_heads, max_seq, cfg.d_head)
+    """The KV arena: K and V as (n_layers, B, Hkv, d_head, max_seq), the
+    sequence axis minor. That is the order the TPU stores it in (a
+    d_head of 64 minor would pad to 128 lanes), so decode attention
+    reads it in place (docs/serving.md)."""
+    shape = (n_layers, batch, cfg.n_kv_heads, cfg.d_head, max_seq)
     return {"k": zeros(shape, dtype), "v": zeros(shape, dtype)}
 
 
 def attn_decode(cfg: ArchConfig, p: dict, x: jax.Array, cache_k: jax.Array,
-                cache_v: jax.Array, pos: jax.Array,
+                cache_v: jax.Array, layer, pos: jax.Array,
                 window: jax.Array | int | None = None,
                 start: jax.Array | None = None
                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token decode. x: (B, 1, D); cache_k/v: (B, Hkv, S, D);
-    pos: scalar — index where the new token is written. ``start``,
-    when given, is a (B,) vector of per-slot window origins for
-    token-level continuous batching: slot b attends only to cache
-    positions in [start[b], pos], hiding the previous occupant's stale
-    K/V (always below start[b], since the arena cursor only advances).
-    Returns (out, new_cache_k, new_cache_v)."""
+    """One-token decode of layer ``layer``. x: (B, 1, D); cache_k/v: the
+    whole stacked arena (L, B, Hkv, D, S), read where it lies; pos:
+    scalar — the position of the new token. ``start``, when given, is a
+    (B,) vector of per-slot window origins for token-level continuous
+    batching: slot b attends only to cache positions in [start[b], pos],
+    hiding the previous occupant's stale K/V (always below start[b],
+    since the arena cursor only advances). Returns (out, k, v): the new
+    token's K/V (B, Hkv, D) in the arena's dtype, for the caller to
+    write at ``pos`` (:func:`write_kv`) once every layer has run."""
     B = x.shape[0]
     positions = jnp.full((1,), pos)
     q, k, v = _qkv(cfg, p, x, positions)
-    cache_k = lax.dynamic_update_slice_in_dim(cache_k, k.astype(cache_k.dtype),
-                                              pos, axis=2)
-    cache_v = lax.dynamic_update_slice_in_dim(cache_v, v.astype(cache_v.dtype),
-                                              pos, axis=2)
-    S = cache_k.shape[2]
+    k = k[:, :, 0].astype(cache_k.dtype)
+    v = v[:, :, 0].astype(cache_v.dtype)
     win = window if window is not None else 0
-    o = _decode_attention(q, cache_k, cache_v, pos, win, cfg.attn_softcap,
-                          start=start)
+    o = ops.decode_attention(q, cache_k, cache_v, k, v, layer, pos,
+                             window=win, softcap=cfg.attn_softcap,
+                             start=start)
     o = o.transpose(0, 2, 1, 3).reshape(B, 1, cfg.d_q)
     out = jnp.einsum("bse,ed->bsd", o, p["wo"].astype(x.dtype))
-    return out, cache_k, cache_v
+    return out, k, v
 
 
-def _decode_attention(q, cache_k, cache_v, pos, window,
-                      softcap: float | None,
-                      start: jax.Array | None = None) -> jax.Array:
-    """q: (B, Hq, 1, D) vs full cache; masks unwritten and out-of-window
-    positions, plus per-batch positions below ``start`` (stale cache
-    from a slot's previous occupant). ``window`` may be traced (0 =
-    unlimited). Masking (not zeroing) is load-bearing for slot reuse: a
-    zeroed K row still gets softmax weight exp(0), so stale rows must be
-    excluded from the normalization, and rotary phases stay correct
-    because only relative distances inside [start, pos] survive.
-
-    The cache stays in its storage dtype — an ``astype(f32)`` here gets
-    hoisted by the compiler into a full f32 copy of the *whole stacked
-    cache* (2x the serving HBM); f32 accumulation comes from
-    ``preferred_element_type`` instead (EXPERIMENTS.md §Perf)."""
-    B, Hq, _, D = q.shape
-    Hkv = cache_k.shape[1]
-    group = Hq // Hkv
-    qg = q.reshape(B, Hkv, group, D).astype(cache_k.dtype)
-    s = jnp.einsum("bhgd,bhkd->bhgk", qg, cache_k,
-                   preferred_element_type=jnp.float32) * (D ** -0.5)
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-    k_pos = jnp.arange(cache_k.shape[2])
-    valid = k_pos <= pos
-    valid &= jnp.where(window > 0, (pos - k_pos) < window, True)
-    if start is None:
-        mask = valid[None, None, None, :]
-    else:
-        mask = (valid[None, :]
-                & (k_pos[None, :] >= start[:, None]))[:, None, None, :]
-    s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgk,bhkd->bhgd", p.astype(cache_v.dtype), cache_v,
-                   preferred_element_type=jnp.float32)
-    return o.reshape(B, Hq, 1, D).astype(q.dtype)
+def write_kv(kv: dict, k: jax.Array, v: jax.Array, pos) -> dict:
+    """Write one position of every layer into the arena: k/v
+    (L, B, Hkv, D) at ``pos``. Under a jit that donates the arena this is
+    the step's one in-place write."""
+    k_arena, v_arena = ops.write_kv_position(kv["k"], kv["v"], k, v, pos)
+    return {"k": k_arena, "v": v_arena}
 
 
 # ----------------------------------------------------------- cross-attn ----

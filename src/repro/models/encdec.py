@@ -193,10 +193,13 @@ class EncDecLM:
         x = self._dec_embed(p, tokens, pos0=pos)
         cache = dict(cache)
 
+        kv, xkv = cache["kv"], cache["cross_kv"]
+
         def body(xc, inp):
-            blk, kl, vl, ck, cv = inp
+            blk, layer, ck, cv = inp
             h = apply_norm(cfg, blk["ln1"], xc)
-            o, nk, nv = A.attn_decode(cfg, blk["attn"], h, kl, vl, pos)
+            o, nk, nv = A.attn_decode(cfg, blk["attn"], h, kv["k"], kv["v"],
+                                      layer, pos)
             xc = xc + o
             h = apply_norm(cfg, blk["ln_x"], xc)
             xc = xc + A.cross_attn_forward(cfg, blk["cross"], h, ck, cv,
@@ -204,10 +207,10 @@ class EncDecLM:
             h = apply_norm(cfg, blk["ln2"], xc)
             return xc + apply_mlp(cfg, blk["mlp"], h), (nk, nv)
 
-        kv, xkv = cache["kv"], cache["cross_kv"]
         x, (nk, nv) = lax.scan(
-            body, x, (p["decoder"], kv["k"], kv["v"], xkv["k"], xkv["v"]))
-        cache["kv"] = {"k": nk, "v": nv}
+            body, x, (p["decoder"], jnp.arange(cfg.n_layers), xkv["k"],
+                      xkv["v"]))
+        cache["kv"] = A.write_kv(kv, nk, nv, pos)
         cache["pos"] = pos + 1
         x = apply_norm(cfg, p["final_norm"], x)
         return self._head(p, x), cache

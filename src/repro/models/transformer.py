@@ -311,12 +311,15 @@ class DecoderLM:
                                                 dtype)
         return cache
 
-    def _block_decode(self, blk: dict, x, window, pos, kv=None, mla=None,
-                      mamba=None, start=None):
-        """One-layer decode. Returns (x, new_kv, new_mla, new_mamba).
-        ``start`` (per-slot attention-window origins, token-level
-        serving) only reaches the plain-attention path — the
-        :attr:`decode_supports_start` gate keeps it None elsewhere."""
+    def _block_decode(self, blk: dict, x, window, pos, kv=None, layer=None,
+                      mla=None, mamba=None, start=None):
+        """One-layer decode. ``kv`` is the whole (k, v) arena and
+        ``layer`` this layer's index in it; the new K/V comes back for
+        the caller to write once every layer has run. Returns (x, new_kv,
+        new_mla, new_mamba). ``start`` (per-slot attention-window
+        origins, token-level serving) only reaches the plain-attention
+        path — the :attr:`decode_supports_start` gate keeps it None
+        elsewhere."""
         cfg = self.cfg
         h = apply_norm(cfg, blk["ln1"], x)
         new_kv = new_mla = new_mamba = None
@@ -325,9 +328,9 @@ class DecoderLM:
                                           pos)
             new_mla = (ckv, kpe)
         else:
-            mix, ck, cv = A.attn_decode(cfg, blk["attn"], h, kv[0], kv[1],
-                                        pos, window=window, start=start)
-            new_kv = (ck, cv)
+            mix, k, v = A.attn_decode(cfg, blk["attn"], h, kv[0], kv[1],
+                                      layer, pos, window=window, start=start)
+            new_kv = (k, v)
         if cfg.mixer == "mamba+attn":
             mo, ssm, win = M.mamba_decode(cfg, blk["mamba"], h, mamba[0],
                                           mamba[1])
@@ -414,6 +417,7 @@ class DecoderLM:
         n_dense = len(cfg.dense_layers) if cfg.moe is not None else 0
         use_mla = cfg.mla is not None
 
+        new_dense = []
         for i in range(n_dense):
             blk = p["dense_prefix"][i]
             if use_mla:
@@ -426,52 +430,49 @@ class DecoderLM:
                     "k_pe": md["k_pe"].at[i].set(nm[1])}
             else:
                 kd = cache["kv_dense"]
-                x, nk, _, _ = self._block_decode(
+                x, nkv, _, _ = self._block_decode(
                     blk, x, int(cfg.layer_windows[i]), pos,
-                    kv=(kd["k"][i], kd["v"][i]), start=start)
-                cache["kv_dense"] = {"k": kd["k"].at[i].set(nk[0]),
-                                     "v": kd["v"].at[i].set(nk[1])}
+                    kv=(kd["k"], kd["v"]), layer=i, start=start)
+                new_dense.append(nkv)
+        if new_dense:
+            cache["kv_dense"] = A.write_kv(
+                cache["kv_dense"], *(jnp.stack(t) for t in zip(*new_dense)),
+                pos)
 
         has_mamba = cfg.mixer == "mamba+attn"
+        kv = None if use_mla else (cache["kv"]["k"], cache["kv"]["v"])
 
         def body(xc, inp):
-            blk, win, kv_l, mla_l, mamba_l = inp
+            blk, win, layer, mla_l, mamba_l = inp
             xc, nkv, nmla, nmb = self._block_decode(
-                blk, xc, win, pos, kv=kv_l, mla=mla_l, mamba=mamba_l,
-                start=start)
+                blk, xc, win, pos, kv=kv, layer=layer, mla=mla_l,
+                mamba=mamba_l, start=start)
             return xc, (nkv, nmla, nmb)
 
-        if use_mla:
-            mla_xs = (cache["mla"]["c_kv"], cache["mla"]["k_pe"])
-            kv_xs = None
-        else:
-            kv_xs = (cache["kv"]["k"], cache["kv"]["v"])
-            mla_xs = None
+        mla_xs = (cache["mla"]["c_kv"], cache["mla"]["k_pe"]) if use_mla \
+            else None
         mamba_xs = (cache["mamba"]["ssm"], cache["mamba"]["conv"]) \
             if has_mamba else None
 
-        xs = (p["layers"], windows[n_dense:], kv_xs, mla_xs, mamba_xs)
+        layers = jnp.arange(cfg.n_layers - n_dense)
+        xs = (p["layers"], windows[n_dense:], layers, mla_xs, mamba_xs)
         x, (nkv, nmla, nmb) = lax.scan(body, x, xs)
         if use_mla:
             cache["mla"] = {"c_kv": nmla[0], "k_pe": nmla[1]}
         else:
-            cache["kv"] = {"k": nkv[0], "v": nkv[1]}
+            cache["kv"] = A.write_kv(cache["kv"], *nkv, pos)
         if has_mamba:
             cache["mamba"] = {"ssm": nmb[0], "conv": nmb[1]}
         return x, cache
 
     def _vision_decode(self, p, x, cache, pos):
         cfg = self.cfg
-        period = cfg.cross_attn_period
-        n_self = period - 1
+        n_self = cfg.cross_attn_period - 1
         kv = cache["kv"]
         groups = kv["k"].shape[0] // n_self
-        kshape = kv["k"].shape
-        k_g = kv["k"].reshape(groups, n_self, *kshape[1:])
-        v_g = kv["v"].reshape(groups, n_self, *kshape[1:])
 
         def group(xc, inp):
-            cross, selfs, ck, cv, kg, vg = inp
+            cross, selfs, ck, cv, g = inp
             h = apply_norm(cfg, cross["ln"], xc)
             xc = xc + A.cross_attn_forward(cfg, cross["attn"], h, ck, cv)
             h = apply_norm(cfg, cross["ln2"], xc)
@@ -479,18 +480,19 @@ class DecoderLM:
                 * jnp.tanh(cross["attn"]["gate"]).astype(xc.dtype)
 
             def self_layer(xi, inp2):
-                blk, kl, vl = inp2
-                xi, nkv, _, _ = self._block_decode(blk, xi, FULL_WINDOW, pos,
-                                                   kv=(kl, vl))
+                blk, i = inp2
+                xi, nkv, _, _ = self._block_decode(
+                    blk, xi, FULL_WINDOW, pos, kv=(kv["k"], kv["v"]),
+                    layer=g * n_self + i)
                 return xi, nkv
 
-            xc, (nk, nv) = lax.scan(self_layer, xc, (selfs, kg, vg))
-            return xc, (nk, nv)
+            return lax.scan(self_layer, xc, (selfs, jnp.arange(n_self)))
 
         x, (nk, nv) = lax.scan(
             group, x, (p["cross"], p["layers"], cache["cross_kv"]["k"],
-                       cache["cross_kv"]["v"], k_g, v_g))
-        cache["kv"] = {"k": nk.reshape(kshape), "v": nv.reshape(kshape)}
+                       cache["cross_kv"]["v"], jnp.arange(groups)))
+        flat = (groups * n_self, *nk.shape[2:])
+        cache["kv"] = A.write_kv(kv, nk.reshape(flat), nv.reshape(flat), pos)
         return x, cache
 
     # --------------------------------------------------------- prefill ----

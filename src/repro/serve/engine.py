@@ -158,7 +158,9 @@ class ServeEngine:
                     else "cohort")
         self.mode = mode
         self.batcher = ContinuousBatcher(n_slots, max_seq)
-        self._decode = jax.jit(model.decode_step)
+        # The cache is donated: the step writes the arena in place, and
+        # every caller rebinds the cache to the step's result.
+        self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
         self._requests: dict[int, Request] = {}
         self._rng = np.random.default_rng(rng_seed)
         self.steps_run = 0

@@ -8,6 +8,9 @@ topology is described inside a module fixture: only the worker that runs
 this file loads the TPU compiler.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -72,20 +75,39 @@ def test_flash_causal_default_compiles(one_chip, v5e):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_stablelm_decode_step_compiles_full_width(one_chip, v5e):
+def test_stablelm_decode_step_compiles_full_width(one_chip, v5e,
+                                                  monkeypatch):
+    """The serve cell's step (8 slots x 2048, per-slot ``start``, cache
+    donated) reads the KV arena in place: the arena is aliased, the
+    decode-attention kernel is in the program, and no copy as large as
+    one layer's arena is left (per-layer relayout copies stayed under
+    donation alone while XLA laid out the in-loop attention)."""
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
     model = build_model(get_arch("stablelm-1.6b"))
-    slots, max_seq = 8, 1024
+    slots, max_seq = 8, 2048
 
     def on_chip(tree):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=one_chip), tree)
 
     params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    cache = on_chip(jax.eval_shape(lambda: model.init_cache(slots, max_seq)))
+    cache = jax.eval_shape(lambda: model.init_cache(slots, max_seq))
+    cache["start"] = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    cache = on_chip(cache)
     tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(model.decode_step).lower(params, cache,
-                                                tokens).compile()
+    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, cache, tokens).compile()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < V5E_HBM_BYTES
+    kv_bytes = sum(a.size * a.dtype.itemsize
+                   for a in jax.tree.leaves(cache["kv"]))
+    assert mem.alias_size_in_bytes >= kv_bytes
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    layer_arena = slots * model.cfg.n_kv_heads * max_seq * model.cfg.d_head
+    copies = re.findall(r"= \w+\[([\d,]*)\]\S* copy\(", text)
+    big = [c for c in copies
+           if math.prod(int(d) for d in c.split(",") if d) >= layer_arena]
+    assert not big, f"arena-sized copies: {big}"
