@@ -20,6 +20,7 @@ from . import decode_attention as _da_mod
 from . import diff_uvw as _diff_mod
 from . import flash_attention as _fa_mod
 from . import matmul as _mm_mod
+from . import mla_attention as _mla_mod
 from . import ref
 
 advec_u_kernel = WisdomKernel(_advec_mod.builder)
@@ -28,9 +29,11 @@ matmul_kernel = WisdomKernel(_mm_mod.builder)
 fa_causal_kernel = WisdomKernel(_fa_mod.causal_builder)
 fa_full_kernel = WisdomKernel(_fa_mod.full_builder)
 decode_attention_kernel = WisdomKernel(_da_mod.builder)
+mla_attention_kernel = WisdomKernel(_mla_mod.builder)
 
 _ALL_KERNELS = (advec_u_kernel, diff_uvw_kernel, matmul_kernel,
-                fa_causal_kernel, fa_full_kernel, decode_attention_kernel)
+                fa_causal_kernel, fa_full_kernel, decode_attention_kernel,
+                mla_attention_kernel)
 
 
 def reload_wisdom() -> None:
@@ -154,6 +157,39 @@ def decode_attention(q, cache_k, cache_v, k_new, v_new, layer, pos, *,
         start = jnp.zeros((q.shape[0],), jnp.int32)
     return decode_attention_kernel(cache_k, cache_v, q, k_new, v_new, ints,
                                    start, cap)
+
+
+#: Always-on tally of the route every :func:`mla_decode_attention` call
+#: took, as :data:`decode_attention_routes` does for GQA.
+mla_decode_attention_routes: dict[str, int] = {}
+
+
+def mla_decode_attention(q_c, q_pe, arena, new, layer, pos, *, scale: float,
+                         start=None):
+    """Absorbed latent attention of one query token per slot against
+    layer ``layer`` of the latent arena.
+
+    q_c: (B, H, dv), the nope queries through ``W^UK``; q_pe: (B, H, dr),
+    the roped queries; arena: the whole stacked latent arena
+    (L, B, S, dv + dr), read where it lies; new: (B, dv + dr), the
+    step's own latent row, attended at ``pos`` though the arena does not
+    hold it yet. ``start`` (B,) scopes slot b to positions
+    [start[b], pos]. Returns (B, H, dv) float32. Routes to the Pallas
+    kernel (:mod:`repro.kernels.mla_attention`) where the backend and
+    the arena's length allow, else to its jnp oracle; each call's route
+    is tallied in :data:`mla_decode_attention_routes`.
+    """
+    route = _decode_attention_route(arena.shape[2])
+    mla_decode_attention_routes[route] = \
+        mla_decode_attention_routes.get(route, 0) + 1
+    ints = jnp.stack([jnp.asarray(x, jnp.int32) for x in (layer, pos)])
+    scale = jnp.full((1,), scale, jnp.float32)
+    if route != "pallas":
+        return _mla_mod.mla_attention_ref(arena, q_c, q_pe, new, ints, start,
+                                          scale)
+    if start is None:
+        start = jnp.zeros((q_c.shape[0],), jnp.int32)
+    return mla_attention_kernel(arena, q_c, q_pe, new, ints, start, scale)
 
 
 def write_kv_position(k_arena, v_arena, k, v, pos):

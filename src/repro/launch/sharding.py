@@ -204,8 +204,8 @@ def _cache_spec(path_names, leaf, mesh) -> P:
             assign[seq] = "model" if _fit(mesh, shape[1], dp) else \
                 ("data", "model")
         return _spec(mesh, shape, assign)
-    if name in ("c_kv", "k_pe"):
-        # (L, B, S, lat)
+    if name == "latent":
+        # MLA latent arena (L, B, S, kv_lora + d_rope)
         assign = {1: dp, 2: "model"}
         if not _fit(mesh, shape[1], dp):
             assign = {2: ("data", "model")}

@@ -7,6 +7,8 @@ PRNG key and returns the dict. Layer-stacked parameters carry a leading
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -120,26 +122,59 @@ def apply_norm(cfg: ArchConfig, p: dict, x: jax.Array) -> jax.Array:
 
 # --------------------------------------------------------------- rotary ----
 
-def rope_freqs(d_rot: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, d_rot, 2, dtype=jnp.float32)
-                            / d_rot))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude factor ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_ramp(d_rot: int, theta: float, y) -> tuple[int, int]:
+    """The YaRN ramp's (low, high) rope-pair indices: pairs below ``low``
+    turn more than ``beta_fast`` times over ``original_max_seq`` and keep
+    their frequency, pairs above ``high`` fewer than ``beta_slow`` times
+    and are interpolated."""
+    def corr(turns):
+        return (d_rot * math.log(y.original_max_seq / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(corr(y.beta_fast)), 0),
+            min(math.ceil(corr(y.beta_slow)), d_rot - 1))
+
+
+def rope_freqs(d_rot: int, theta: float, scaling=None) -> jax.Array:
+    """Inverse frequencies of the ``d_rot / 2`` rope pairs; with a
+    :class:`~repro.models.config.YarnCfg`, YaRN's blend of the original
+    and the ``/ factor`` interpolated ones (DeepseekV2YarnRotaryEmbedding)."""
+    extra = 1.0 / (theta ** (jnp.arange(0, d_rot, 2, dtype=jnp.float32)
+                             / d_rot))
+    if scaling is None:
+        return extra
+    low, high = yarn_ramp(d_rot, theta, scaling)
+    span = (high - low) if high != low else 0.001
+    ramp = jnp.clip((jnp.arange(d_rot // 2, dtype=jnp.float32) - low)
+                    / span, 0.0, 1.0)
+    return extra / scaling.factor * ramp + extra * (1.0 - ramp)
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, rope_frac: float,
-               theta: float) -> jax.Array:
+               theta: float, scaling=None) -> jax.Array:
     """x: (..., S, H, D) or (..., S, D); positions: (..., S) or (S,).
-    Rotates the first ``rope_frac * D`` dims (partial rotary, stablelm)."""
+    Rotates the first ``rope_frac * D`` dims (partial rotary, stablelm),
+    pairs interleaved; ``scaling`` is an optional YaRN description."""
     d = x.shape[-1]
     d_rot = int(d * rope_frac)
     d_rot -= d_rot % 2
     if d_rot == 0:
         return x
     rot, keep = x[..., :d_rot], x[..., d_rot:]
-    freqs = rope_freqs(d_rot, theta)                        # (d_rot/2,)
+    freqs = rope_freqs(d_rot, theta, scaling)              # (d_rot/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs
     if x.ndim - positions.ndim == 3:                        # head axis present
         angles = angles[..., None, :]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     r1, r2 = rot[..., ::2], rot[..., 1::2]
     o1 = r1 * cos - r2 * sin
     o2 = r2 * cos + r1 * sin

@@ -25,6 +25,41 @@ class MoECfg:
     router_aux_weight: float = 0.01
     router_z_weight: float = 1e-3
     chunk: int = 256             # seq chunk for capacity dispatch
+    # routing: softmax scores; with n_group > 1, group-limited greedy
+    # (keep the topk_group groups with the best single score, pick top_k
+    # among their experts); gates renormalised over the top_k when
+    # norm_topk, then scaled by routed_scale
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+    # experts whose weights this layer holds: (first, count); None = all.
+    # The router still scores all n_routed.
+    held: tuple[int, int] | None = None
+
+    @property
+    def held_range(self) -> tuple[int, int]:
+        return self.held if self.held is not None else (0, self.n_routed)
+
+    @property
+    def n_held(self) -> int:
+        return self.held_range[1]
+
+
+@dataclass(frozen=True)
+class YarnCfg:
+    """YaRN rope scaling (arXiv:2309.00071), as DeepSeek-V2 publishes it:
+    rope frequencies blended between interpolated (``/ factor``) and
+    original by a linear ramp over the dims whose wavelengths lie between
+    ``beta_fast`` and ``beta_slow`` turns of ``original_max_seq``; the
+    attention scale gains ``mscale(mscale_all_dim) ** 2`` and cos/sin
+    ``mscale(mscale) / mscale(mscale_all_dim)``."""
+    factor: float
+    original_max_seq: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -71,6 +106,7 @@ class ArchConfig:
     qkv_bias: bool = False
     rope_frac: float = 1.0
     rope_theta: float = 10000.0
+    rope_scaling: YarnCfg | None = None
 
     # norms / MLP
     norm: Literal["rms", "ln"] = "rms"
@@ -171,8 +207,8 @@ class ArchConfig:
                     per += di * mb.d_conv + di * mb.d_state + di
                 if self.moe is not None and i not in self.dense_layers:
                     e = self.moe
-                    per += d * e.n_routed
-                    per += (e.n_routed + e.n_shared) * mlp(e.d_expert)
+                    per += d * e.n_routed                   # router
+                    per += (e.n_held + e.n_shared) * mlp(e.d_expert)
                 else:
                     per += mlp(f)
             total += per
@@ -197,7 +233,7 @@ class ArchConfig:
         e = self.moe
         expert = (3 if self.gated_mlp else 2) * self.d_model * e.d_expert
         n_moe = self.n_layers - len(self.dense_layers)
-        inactive = n_moe * (e.n_routed - e.top_k) * expert
+        inactive = n_moe * max(e.n_held - e.top_k, 0) * expert
         return self.n_params() - inactive
 
     def reduced(self, **overrides) -> "ArchConfig":
@@ -224,9 +260,14 @@ class ArchConfig:
         if self.moe is not None:
             # capacity_factor 4 => no token drops, so decode == forward
             # exactly (capacity dropping is train-time-only behavior)
-            d["moe"] = replace(self.moe, n_routed=4, top_k=2, d_expert=32,
+            # grouped routing keeps groups: 8 experts in 4 groups of 2
+            grouped = self.moe.n_group > 1
+            d["moe"] = replace(self.moe, n_routed=8 if grouped else 4,
+                               top_k=2, d_expert=32,
                                n_shared=min(self.moe.n_shared, 1), chunk=16,
-                               capacity_factor=4.0)
+                               capacity_factor=4.0,
+                               n_group=4 if grouped else 1,
+                               topk_group=2 if grouped else 1, held=None)
             d["dense_layers"] = tuple(x for x in self.dense_layers
                                       if x < d["n_layers"])
         if self.mla is not None:

@@ -7,7 +7,8 @@ One scanned layer body per family keeps HLO size O(1) in depth:
   * mamba+attn (hymba): parallel attention and SSM heads per layer;
   * rwkv (rwkv6): time-mix + channel-mix;
   * moe (deepseek-moe, deepseek-v2): dense-FFN prefix layers outside the
-    scan, MoE layers scanned; deepseek-v2 additionally swaps GQA for MLA;
+    scan, MoE layers scanned; deepseek-v2 additionally swaps GQA for MLA,
+    whose decode reads one latent arena of every layer in place;
   * vision (llama-3.2-vision): period-grouped scan — each group is one
     gated cross-attention block + (period-1) self-attention layers.
 
@@ -43,6 +44,9 @@ Cache = dict
 class DecoderLM:
     cfg: ArchConfig
     remat: bool = False     # activation-checkpoint each scanned layer
+    # keep every decoded token's experts in the decode cache
+    # (``moe_picks``, (MoE layers, B, S, top_k)), for comparing routing
+    record_routing: bool = False
 
     def _maybe_remat(self, body):
         if not self.remat:
@@ -295,12 +299,14 @@ class DecoderLM:
                 "v": jnp.zeros((groups, batch, cfg.n_kv_heads,
                                 cfg.n_img_tokens, cfg.d_head), dtype)}
             return cache
+        if cfg.moe is not None:
+            cache["moe_routed"] = jnp.zeros((), jnp.int32)
+            if self.record_routing:
+                cache["moe_picks"] = jnp.zeros(
+                    (n_scan, batch, max_seq, cfg.moe.top_k), jnp.int32)
         if cfg.mla is not None:
-            cache["mla"] = ML.init_mla_cache(cfg, n_scan, batch, max_seq,
-                                             dtype)
-            if n_dense:
-                cache["mla_dense"] = ML.init_mla_cache(cfg, n_dense, batch,
-                                                       max_seq, dtype)
+            cache["mla"] = ML.init_mla_cache(cfg, cfg.n_layers, batch,
+                                             max_seq, dtype)
         else:
             cache["kv"] = A.init_kv_cache(cfg, n_scan, batch, max_seq, dtype)
             if n_dense:
@@ -313,20 +319,20 @@ class DecoderLM:
 
     def _block_decode(self, blk: dict, x, window, pos, kv=None, layer=None,
                       mla=None, mamba=None, start=None):
-        """One-layer decode. ``kv`` is the whole (k, v) arena and
-        ``layer`` this layer's index in it; the new K/V comes back for
-        the caller to write once every layer has run. Returns (x, new_kv,
-        new_mla, new_mamba). ``start`` (per-slot attention-window
-        origins, token-level serving) only reaches the plain-attention
-        path — the :attr:`decode_supports_start` gate keeps it None
-        elsewhere."""
+        """One-layer decode. ``kv`` is the whole (k, v) arena, ``mla`` the
+        whole latent arena, and ``layer`` this layer's index in it; the
+        new K/V or latent row comes back for the caller to write once
+        every layer has run. Returns (x, new_kv, new_mla, new_mamba,
+        moe_aux), the last the expert layer's aux (None without
+        experts). ``start`` (per-slot attention-window
+        origins, token-level serving) only reaches the attention paths —
+        the :attr:`decode_supports_start` gate keeps it None elsewhere."""
         cfg = self.cfg
         h = apply_norm(cfg, blk["ln1"], x)
         new_kv = new_mla = new_mamba = None
         if cfg.mla is not None:
-            mix, ckv, kpe = ML.mla_decode(cfg, blk["mla"], h, mla[0], mla[1],
-                                          pos)
-            new_mla = (ckv, kpe)
+            mix, new_mla = ML.mla_decode(cfg, blk["mla"], h, mla, layer, pos,
+                                         start=start)
         else:
             mix, k, v = A.attn_decode(cfg, blk["attn"], h, kv[0], kv[1],
                                       layer, pos, window=window, start=start)
@@ -340,27 +346,36 @@ class DecoderLM:
             mix = apply_norm(cfg, blk["post_ln1"], mix)
         x = x + mix
         h = apply_norm(cfg, blk["ln2"], x)
+        aux = None
         if "moe" in blk:
-            y, _ = MO.moe_ffn(cfg, blk["moe"], h)
+            y, aux = MO.moe_ffn(cfg, blk["moe"], h)
         else:
             y = apply_mlp(cfg, blk["mlp"], h)
         if cfg.post_norm:
             y = apply_norm(cfg, blk["post_ln2"], y)
-        return x + y, new_kv, new_mla, new_mamba
+        return x + y, new_kv, new_mla, new_mamba, aux
 
     @property
     def decode_supports_start(self) -> bool:
         """Whether :meth:`decode_step` honors a per-slot ``cache["start"]``
         vector (token-level continuous batching, ``repro.serve``). True
-        only for plain rotary/positionless attention stacks: recurrent
-        mixers (rwkv, mamba+attn) carry state that a mask cannot scope to
-        one slot's window, cross-attention and MLA caches are not
-        start-masked, and learned positional embeddings index absolute
-        arena positions. ``ServeEngine(mode="auto")`` reads this to pick
-        token-level vs cohort scheduling."""
+        for rotary/positionless attention stacks, GQA or MLA (both
+        arenas are start-masked): recurrent mixers (rwkv, mamba+attn)
+        carry state that a mask cannot scope to one slot's window,
+        cross-attention caches are not start-masked, and learned
+        positional embeddings index absolute arena positions.
+        ``ServeEngine(mode="auto")`` reads this to pick token-level vs
+        cohort scheduling."""
         cfg = self.cfg
-        return (cfg.mixer == "attn" and cfg.mla is None
-                and not cfg.cross_attn_period and cfg.pos != "learned")
+        return (cfg.mixer == "attn" and not cfg.cross_attn_period
+                and cfg.pos != "learned")
+
+    @property
+    def cache_tallies(self) -> tuple[str, ...]:
+        """Scalar int32 entries of the decode cache that count work on the
+        device, step after step (``moe_routed``: (token, k) pairs the
+        held experts computed); ``ServeEngine.tallies`` sums them."""
+        return ("moe_routed",) if self.cfg.moe is not None else ()
 
     def decode_step(self, p: Params, cache: Cache, tokens: jax.Array
                     ) -> tuple[jax.Array, Cache]:
@@ -416,25 +431,20 @@ class DecoderLM:
         windows = self._windows()
         n_dense = len(cfg.dense_layers) if cfg.moe is not None else 0
         use_mla = cfg.mla is not None
+        # the latent arena holds every layer, dense prefix first; the KV
+        # path keeps the prefix in an arena of its own
+        mla = cache["mla"]["latent"] if use_mla else None
 
         new_dense = []
+        kd = None
+        if n_dense and not use_mla:
+            kd = (cache["kv_dense"]["k"], cache["kv_dense"]["v"])
         for i in range(n_dense):
-            blk = p["dense_prefix"][i]
-            if use_mla:
-                md = cache["mla_dense"]
-                x, _, nm, _ = self._block_decode(
-                    blk, x, int(cfg.layer_windows[i]), pos,
-                    mla=(md["c_kv"][i], md["k_pe"][i]))
-                cache["mla_dense"] = {
-                    "c_kv": md["c_kv"].at[i].set(nm[0]),
-                    "k_pe": md["k_pe"].at[i].set(nm[1])}
-            else:
-                kd = cache["kv_dense"]
-                x, nkv, _, _ = self._block_decode(
-                    blk, x, int(cfg.layer_windows[i]), pos,
-                    kv=(kd["k"], kd["v"]), layer=i, start=start)
-                new_dense.append(nkv)
-        if new_dense:
+            x, nkv, nm, _, _ = self._block_decode(
+                p["dense_prefix"][i], x, int(cfg.layer_windows[i]), pos,
+                kv=kd, layer=i, mla=mla, start=start)
+            new_dense.append(nm if use_mla else nkv)
+        if new_dense and not use_mla:
             cache["kv_dense"] = A.write_kv(
                 cache["kv_dense"], *(jnp.stack(t) for t in zip(*new_dense)),
                 pos)
@@ -443,26 +453,35 @@ class DecoderLM:
         kv = None if use_mla else (cache["kv"]["k"], cache["kv"]["v"])
 
         def body(xc, inp):
-            blk, win, layer, mla_l, mamba_l = inp
-            xc, nkv, nmla, nmb = self._block_decode(
-                blk, xc, win, pos, kv=kv, layer=layer, mla=mla_l,
+            blk, win, layer, mamba_l = inp
+            xc, nkv, nmla, nmb, aux = self._block_decode(
+                blk, xc, win, pos, kv=kv, layer=layer, mla=mla,
                 mamba=mamba_l, start=start)
-            return xc, (nkv, nmla, nmb)
+            return xc, (nkv, nmla, nmb, aux)
 
-        mla_xs = (cache["mla"]["c_kv"], cache["mla"]["k_pe"]) if use_mla \
-            else None
         mamba_xs = (cache["mamba"]["ssm"], cache["mamba"]["conv"]) \
             if has_mamba else None
 
         layers = jnp.arange(cfg.n_layers - n_dense)
-        xs = (p["layers"], windows[n_dense:], layers, mla_xs, mamba_xs)
-        x, (nkv, nmla, nmb) = lax.scan(body, x, xs)
         if use_mla:
-            cache["mla"] = {"c_kv": nmla[0], "k_pe": nmla[1]}
+            layers = layers + n_dense
+        xs = (p["layers"], windows[n_dense:], layers, mamba_xs)
+        x, (nkv, nmla, nmb, aux) = lax.scan(body, x, xs)
+        if use_mla:
+            rows = jnp.concatenate([jnp.stack(new_dense), nmla]) \
+                if new_dense else nmla
+            cache["mla"] = {"latent": ML.write_latents(mla, rows, pos)}
         else:
             cache["kv"] = A.write_kv(cache["kv"], *nkv, pos)
         if has_mamba:
             cache["mamba"] = {"ssm": nmb[0], "conv": nmb[1]}
+        if aux is not None:
+            cache["moe_routed"] = cache["moe_routed"] \
+                + aux["moe_routed"].sum()
+            if "moe_picks" in cache:
+                cache["moe_picks"] = lax.dynamic_update_slice(
+                    cache["moe_picks"], aux["moe_picks"],
+                    (0, 0, jnp.asarray(pos, jnp.int32), 0))
         return x, cache
 
     def _vision_decode(self, p, x, cache, pos):
@@ -481,7 +500,7 @@ class DecoderLM:
 
             def self_layer(xi, inp2):
                 blk, i = inp2
-                xi, nkv, _, _ = self._block_decode(
+                xi, nkv, _, _, _ = self._block_decode(
                     blk, xi, FULL_WINDOW, pos, kv=(kv["k"], kv["v"]),
                     layer=g * n_self + i)
                 return xi, nkv
@@ -525,14 +544,13 @@ class DecoderLM:
         logits = self._head(p, x[:, -1:])
         return logits, cache
 
-    def _block_prefill(self, blk: dict, x, window, kv=None, mla=None,
+    def _block_prefill(self, blk: dict, x, window, kv=None,
                        mamba_on: bool = False):
         cfg = self.cfg
         h = apply_norm(cfg, blk["ln1"], x)
         new_kv = new_mla = new_mamba = None
         if cfg.mla is not None:
-            mix, ckv, kpe = ML.mla_prefill(cfg, blk["mla"], h, mla[0], mla[1])
-            new_mla = (ckv, kpe)
+            mix, new_mla = ML.mla_prefill(cfg, blk["mla"], h)
         else:
             mix, ck, cv = A.attn_prefill(cfg, blk["attn"], h, kv[0], kv[1],
                                          window=window)
@@ -560,15 +578,13 @@ class DecoderLM:
         use_mla = cfg.mla is not None
         has_mamba = cfg.mixer == "mamba+attn"
 
+        dense_rows = []
         for i in range(n_dense):
             blk = p["dense_prefix"][i]
             if use_mla:
-                md = cache["mla_dense"]
                 x, _, nm, _ = self._block_prefill(
-                    blk, x, int(cfg.layer_windows[i]),
-                    mla=(md["c_kv"][i], md["k_pe"][i]))
-                cache["mla_dense"] = {"c_kv": md["c_kv"].at[i].set(nm[0]),
-                                      "k_pe": md["k_pe"].at[i].set(nm[1])}
+                    blk, x, int(cfg.layer_windows[i]))
+                dense_rows.append(nm)
             else:
                 kd = cache["kv_dense"]
                 x, nk, _, _ = self._block_prefill(
@@ -578,18 +594,21 @@ class DecoderLM:
                                      "v": kd["v"].at[i].set(nk[1])}
 
         def body(xc, inp):
-            blk, win, kv_l, mla_l = inp
+            blk, win, kv_l = inp
             xc, nkv, nmla, nmb = self._block_prefill(
-                blk, xc, win, kv=kv_l, mla=mla_l, mamba_on=has_mamba)
+                blk, xc, win, kv=kv_l, mamba_on=has_mamba)
             return xc, (nkv, nmla, nmb)
 
         kv_xs = None if use_mla else (cache["kv"]["k"], cache["kv"]["v"])
-        mla_xs = (cache["mla"]["c_kv"], cache["mla"]["k_pe"]) if use_mla \
-            else None
         x, (nkv, nmla, nmb) = lax.scan(
-            body, x, (p["layers"], windows[n_dense:], kv_xs, mla_xs))
+            body, x, (p["layers"], windows[n_dense:], kv_xs))
         if use_mla:
-            cache["mla"] = {"c_kv": nmla[0], "k_pe": nmla[1]}
+            # latent rows of every layer (L, B, S, width) at [0, S)
+            rows = jnp.concatenate([jnp.stack(dense_rows), nmla]) \
+                if dense_rows else nmla
+            arena = cache["mla"]["latent"]
+            cache["mla"] = {"latent": lax.dynamic_update_slice(
+                arena, rows.astype(arena.dtype), (0, 0, 0, 0))}
         else:
             cache["kv"] = {"k": nkv[0], "v": nkv[1]}
         if has_mamba:

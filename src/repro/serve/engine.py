@@ -5,7 +5,8 @@ Two scheduling modes over the same static (n_slots, max_seq) KV arena
 (see docs/serving.md for the slot lifecycle):
 
 * **token** (default whenever the model's ``decode_supports_start`` says
-  per-slot attention windows work): the arena keeps one physical write
+  per-slot attention windows work — GQA and MLA stacks with rotary or no
+  positions): the arena keeps one physical write
   cursor (``cache["pos"]``) but each slot owns a logical window
   ``[start[b], pos]`` carried in ``cache["start"]``. A request that
   finishes frees its slot *mid-stream*; the next queued request — picked
@@ -18,7 +19,7 @@ Two scheduling modes over the same static (n_slots, max_seq) KV arena
   weight), which also keeps rotary phases correct: only relative
   distances within a slot's own window survive the mask.
 
-* **cohort** (fallback for recurrent mixers, MLA, cross-attention and
+* **cohort** (fallback for recurrent mixers, cross-attention and
   learned-position models — their decode state cannot be scoped to a
   slot window by masking — and the A/B baseline for benchmarks): admit a
   cohort into free slots and run lock-step until every member finishes;
@@ -26,6 +27,12 @@ Two scheduling modes over the same static (n_slots, max_seq) KV arena
   occupancy loss ``benchmarks/serve_throughput.py`` measures.
 
 Greedy (argmax) or temperature sampling.
+
+Device tallies: a model that lists ``cache_tallies`` keeps scalar
+counters in its decode cache, updated on the device every step (the
+held experts' routed (token, k) pairs); :meth:`ServeEngine.tallies`
+pulls them, summed over every arena the engine has run, and no step
+waits for them.
 
 With tracing on (``repro.obs``) every decode step is one ``serve.step``
 span whose children are its phases: ``serve.admit`` (token mode),
@@ -161,6 +168,14 @@ class ServeEngine:
         # The cache is donated: the step writes the arena in place, and
         # every caller rebinds the cache to the step's result.
         self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
+        #: The live decode cache (the last step's), kept so that its
+        #: device tallies can be read; ``_tally_base`` holds those of
+        #: arenas already retired.
+        self.cache = None
+        #: arenas opened so far (generations and cohorts, both modes)
+        self.arenas = 0
+        self._tally_names = tuple(getattr(model, "cache_tallies", ()))
+        self._tally_base = dict.fromkeys(self._tally_names, 0)
         self._requests: dict[int, Request] = {}
         self._rng = np.random.default_rng(rng_seed)
         self.steps_run = 0
@@ -232,6 +247,7 @@ class ServeEngine:
                 cache["start"] = jnp.asarray(starts)
             logits, cache = self._decode(self.params, cache,
                                          jnp.asarray(next_tok))
+            self.cache = cache
         if sampled:
             # Only sampled steps pay the extra sync; the rest overlap
             # the device's work with the host's until the logits pull.
@@ -246,6 +262,25 @@ class ServeEngine:
         device if no sampled step waited already) and sample."""
         with obs.span("serve.sample", cat="serve"):
             return self._sample(np.asarray(logits[:, 0]))
+
+    def _new_cache(self):
+        """A fresh arena; the retired one's tallies join the base."""
+        if self.cache is not None:
+            for name in self._tally_names:
+                self._tally_base[name] += int(self.cache[name])
+            self.cache = None       # free the old arena before the new one
+        self.cache = self.model.init_cache(self.n_slots, self.max_seq)
+        self.arenas += 1
+        return self.cache
+
+    def tallies(self) -> dict[str, int]:
+        """The model's device tallies (``cache_tallies``) summed over every
+        arena this engine has run; reads the live cache from the device."""
+        out = dict(self._tally_base)
+        if self.cache is not None:
+            for name in self._tally_names:
+                out[name] += int(self.cache[name])
+        return out
 
     def _tick_services(self, m) -> None:
         """Per-decode-step collaborator ticks (both modes)."""
@@ -271,7 +306,7 @@ class ServeEngine:
     def _run_cohort(self, members: list[tuple[int, int, int]]) -> None:
         """members: [(slot, request_id, prompt_len)]. Fresh cache; decode
         in lock-step until every member has its tokens."""
-        cache = self.model.init_cache(self.n_slots, self.max_seq)
+        cache = self._new_cache()
         reqs = {slot: self._requests[rid] for slot, rid, _ in members}
         done = {slot: False for slot in reqs}
         next_tok = np.zeros((self.n_slots, 1), np.int32)
@@ -360,7 +395,7 @@ class ServeEngine:
         at the current cursor — until the queue and slots drain or the
         remaining arena cannot hold the next (head-of-line) request."""
         b = self.batcher
-        cache = self.model.init_cache(self.n_slots, self.max_seq)
+        cache = self._new_cache()
         starts = np.zeros(self.n_slots, np.int32)
         fed = [0] * self.n_slots           # prompt tokens fed per slot
         next_tok = np.zeros((self.n_slots, 1), np.int32)

@@ -111,3 +111,51 @@ def test_stablelm_decode_step_compiles_full_width(one_chip, v5e,
     big = [c for c in copies
            if math.prod(int(d) for d in c.split(",") if d) >= layer_arena]
     assert not big, f"arena-sized copies: {big}"
+
+
+def test_deepseek_v2_decode_step_compiles_full_width(one_chip, v5e,
+                                                     monkeypatch):
+    """The serve-prompt-heavy cell's step: DeepSeek-V2 at published widths
+    cut to 6 layers (dense layer 0 and 5 MoE layers holding experts
+    0-19 of 160), 16 slots x 8192, per-slot ``start``, cache donated,
+    every token's experts kept as the benchmark keeps them. The latent
+    arena and the experts' record are aliased, the latent kernel is in
+    the program, and no buffer of the arena's size is copied: each layer
+    reads the arena where it lies and the step writes its latent rows
+    once."""
+    import dataclasses
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
+    full = get_arch("deepseek-v2-236b")
+    cfg = dataclasses.replace(full, n_layers=6, moe=dataclasses.replace(
+        full.moe, held=(0, 20)))
+    model = dataclasses.replace(build_model(cfg), record_routing=True)
+    slots, max_seq = 16, 8192
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: model.init_cache(slots, max_seq))
+    cache["start"] = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    cache = on_chip(cache)
+    tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, cache, tokens).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < total < V5E_HBM_BYTES
+    arena = cache["mla"]["latent"]
+    arena_bytes = arena.size * arena.dtype.itemsize
+    picks = cache["moe_picks"]
+    assert mem.alias_size_in_bytes >= arena_bytes \
+        + picks.size * picks.dtype.itemsize
+    assert mem.temp_size_in_bytes < arena_bytes // 6
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    layer_arena = slots * max_seq * arena.shape[-1]
+    copies = re.findall(r"= \w+\[([\d,]*)\]\S* copy\(", text)
+    big = [c for c in copies
+           if math.prod(int(d) for d in c.split(",") if d) >= layer_arena]
+    assert not big, f"arena-sized copies: {big}"

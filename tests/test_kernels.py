@@ -126,6 +126,7 @@ def test_workloads_defined_for_all_kernels():
                    "matmul": (256, 256, 256),
                    "flash_attention_causal": (8, 2, 512, 128),
                    "flash_attention_full": (8, 2, 512, 128),
-                   "decode_attention": (8, 32, 32, 64, 2048)}[name]
+                   "decode_attention": (8, 32, 32, 64, 2048),
+                   "mla_attention": (16, 128, 8192, 512, 64, 640)}[name]
         w = b.make_workload(cfg, problem, "float32")
         assert w.flops > 0 and w.hbm_bytes > 0 and w.vmem_bytes > 0
