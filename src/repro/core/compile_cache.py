@@ -57,13 +57,18 @@ class LaunchStats:
     compile_s: float = 0.0     # trace+lower+compile ("NVRTC" analogue)
     dispatch_s: float = 0.0    # argument wrap + executable call, which
     #                            returns before the device finishes
-    wait_s: float = 0.0        # block_until_ready on the result
+    wait_s: float = 0.0        # block_until_ready on the result; 0.0
+    #                            when the launch did not wait
+    waited: bool = False       # waited for the device: an online tuner
+    #                            or a due profiler reads the latency
     tier: str = ""
     config: dict = field(default_factory=dict)
 
     @property
     def launch_s(self) -> float:
-        """Dispatch plus wait ("cuLaunchKernel" and the sync after it)."""
+        """Dispatch plus wait: the device latency seen from the host on a
+        waited launch, the host's dispatch time ("cuLaunchKernel") on
+        the others."""
         return self.dispatch_s + self.wait_s
 
 

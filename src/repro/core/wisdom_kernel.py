@@ -19,12 +19,25 @@ config instead of the incumbent ("trial" tier), and confident winners are
 promoted into the wisdom file live. Traced launches never participate —
 the outer jit owns those.
 
+An eager launch is as asynchronous as ``cuLaunchKernel``: it returns the
+executable's output without waiting for the device, as ``jax.jit``
+dispatch does. A compile-cache hit only selects and dispatches; the kernel
+is built (``_instantiate``) and compiled on a miss alone. The launch waits
+for its result only when something reads the device latency
+(``LaunchStats.launch_s``): an attached online tuner, or a profiler that
+samples this launch. ``LaunchStats.waited`` says which. The host runs
+ahead of the device by at most one launch of each kernel: before it
+dispatches, a launch waits for the output of the kernel's previous
+launch if that one did not wait.
+
 With tracing on (``repro.obs``), each eager launch is one ``launch`` span
-whose children are its phases: ``launch.select``, ``launch.build``,
-``launch.compile`` (cache misses only), ``launch.dispatch`` and
-``launch.wait``; the parent's time outside them is the preparation
-(argument metadata, problem size, backend, capture check) and the
-bookkeeping after the wait.
+whose children are its phases: ``launch.select``, ``launch.build`` (the
+compile-cache key and lookup, and the build on a miss), ``launch.compile``
+(cache misses only), ``launch.wait`` (before the dispatch, on the previous
+launch still in flight), ``launch.dispatch`` and ``launch.wait`` again
+(after it, on the result, when the latency is read); the parent's time
+outside them is the preparation (argument metadata, problem size,
+backend, capture check) and the bookkeeping after the dispatch.
 """
 
 from __future__ import annotations
@@ -99,6 +112,9 @@ class WisdomKernel:
         #: cost of the disabled site is one attribute check.
         self.profiler = None
         self._profile_baselines: dict[tuple, float | None] = {}
+        #: Output arrays of the last eager launch that did not wait,
+        #: until the next launch has waited for them.
+        self._in_flight = None
         if os.environ.get("KERNEL_LAUNCHER_PROF"):
             from repro.prof.profiler import process_profiler  # deferred
             self.profiler = process_profiler()
@@ -145,11 +161,10 @@ class WisdomKernel:
         backend = resolve_backend(self._backend)
         problem = self.builder.get_problem_size(*meta)
         dtype = self.builder.get_dtype(*meta)
-        key = (self.device_kind, backend, problem, dtype,
-               self.builder.space.freeze(config))
-        fn = self._instantiate(config, meta, backend)
         _, _, cached = self.compile_cache.get_or_compile(
-            key, lambda: self._compile(fn, meta))
+            self._cache_key(backend, problem, dtype, config),
+            lambda: self._compile(self._instantiate(config, meta, backend),
+                                  meta))
         return not cached
 
     def select_config(self, problem: tuple[int, ...], dtype: str
@@ -216,9 +231,12 @@ class WisdomKernel:
 
     def _launch(self, args, config: Config | None, span: dict | None):
         """One eager launch, phase by phase: prepare, ``launch.select``,
-        ``launch.build``, ``launch.compile`` (a cache miss only),
+        ``launch.build`` (the compile-cache lookup; the build on a miss
+        only), ``launch.compile`` (a miss only), ``launch.wait`` on the
+        kernel's previous launch if it is still in flight,
         ``launch.dispatch`` (the executable returns before the device
-        finishes) and ``launch.wait``. ``span`` is the enclosing
+        finishes) and ``launch.wait`` on the result where an online tuner
+        or a due profiler reads the latency. ``span`` is the enclosing
         ``launch`` span's args (None with tracing off): the kernel and
         scenario as soon as they are known, the rest once the launch has
         finished."""
@@ -251,30 +269,50 @@ class WisdomKernel:
         self._observe_selection(problem, dtype, tier)
 
         with obs.span("launch.build", cat="kernel"):
-            fn = self._instantiate(config, meta, backend)
-            key = (self.device_kind, backend, problem, dtype,
-                   self.builder.space.freeze(config))
+            key = self._cache_key(backend, problem, dtype, config)
+            compiled = self.compile_cache.get(key)
+            fn = (self._instantiate(config, meta, backend)
+                  if compiled is None else None)
+        compile_s, cached = 0.0, compiled is not None
+        if not cached:
+            def compile_() -> Callable:
+                with obs.span("launch.compile", cat="kernel"):
+                    return self._compile(fn, meta)
 
-        def compile_() -> Callable:
-            with obs.span("launch.compile", cat="kernel"):
-                return self._compile(fn, meta)
+            compiled, compile_s, _ = self.compile_cache.get_or_compile(
+                key, compile_)
 
-        compiled, compile_s, cached = self.compile_cache.get_or_compile(
-            key, compile_)
-
+        # Wait for this launch only where something reads the latency.
+        profiler = self.profiler
+        profile = profiler is not None and profiler.due(self.builder.name)
+        wait = online is not None or profile
+        # Otherwise the host may run ahead of the device, by one launch
+        # of this kernel: each un-waited launch holds its outputs' memory
+        # until it has run.
+        previous, self._in_flight = self._in_flight, None
+        if previous is not None:
+            with obs.span("launch.wait", cat="kernel"):
+                # an output donated to a later program is gone; that
+                # program runs after this kernel's launch anyway
+                jax.block_until_ready([x for x in previous
+                                       if not x.is_deleted()])
         with obs.span("launch.dispatch", cat="kernel"):
             t0 = time.perf_counter()
             out = compiled(*[np.asarray(a) if not hasattr(a, "dtype") else a
                              for a in args])
             t1 = time.perf_counter()
-        with obs.span("launch.wait", cat="kernel"):
-            out = jax.block_until_ready(out)
-            t2 = time.perf_counter()
+        wait_s = 0.0
+        if wait:
+            with obs.span("launch.wait", cat="kernel"):
+                out = jax.block_until_ready(out)
+                wait_s = time.perf_counter() - t1
+        else:
+            self._in_flight = jax.tree_util.tree_leaves(out)
         st = LaunchStats(
             kernel=self.builder.name, cached=cached,
             wisdom_read_s=0.0 if cached else self._wisdom_read_s,
             select_s=select_s, compile_s=compile_s, dispatch_s=t1 - t0,
-            wait_s=t2 - t1, tier=tier, config=dict(config))
+            wait_s=wait_s, waited=wait, tier=tier, config=dict(config))
         self.stats.append(st)
         launch_s = st.launch_s
         m = obs.metrics()
@@ -283,6 +321,8 @@ class WisdomKernel:
             m.counter("launch.count", kernel=name).inc()
             m.counter("compile.cache", kernel=name,
                       outcome="hit" if cached else "miss").inc()
+            m.counter("launch.wait", kernel=name,
+                      outcome="waited" if wait else "async").inc()
             m.histogram("select.latency_us",
                         kernel=name).observe(select_s * 1e6)
             m.histogram("launch.latency_us",
@@ -291,11 +331,10 @@ class WisdomKernel:
                 m.histogram("compile.latency_us",
                             kernel=name).observe(compile_s * 1e6)
         if span is not None:
-            span.update(tier=tier, cached=cached,
+            span.update(tier=tier, cached=cached, waited=wait,
                         compile_us=round(compile_s * 1e6, 3),
                         launch_us=round(launch_s * 1e6, 3))
-        profiler = self.profiler
-        if profiler is not None and profiler.due(self.builder.name):
+        if profile:
             profiler.profile_launch(
                 self.builder, config, problem, dtype, self.device_kind,
                 launch_s * 1e6, tier=tier,
@@ -312,6 +351,11 @@ class WisdomKernel:
             return fn(*args)
         named.__name__ = named.__qualname__ = self.builder.name
         return jax.jit(named).lower(*meta).compile()
+
+    def _cache_key(self, backend: str, problem: tuple[int, ...], dtype: str,
+                   config: Config) -> tuple:
+        return (self.device_kind, backend, problem, dtype,
+                self.builder.space.freeze(config))
 
     def _instantiate(self, config: Config, meta, backend: str) -> Callable:
         if backend == "reference":

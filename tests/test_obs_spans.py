@@ -109,27 +109,153 @@ def kernel(tmp_path, monkeypatch):
 
 
 def test_one_eager_launch_is_one_launch_span_with_its_phases(kernel):
+    from repro.prof import Profiler
     a = np.ones((64, 64), np.float32)
     _, tr = runtime.enable()
-    kernel(a, a)
-    kernel(a, a)
+    kernel(a, a)                  # a miss: builds and compiles
+    kernel(a, a)                  # a hit, the one before it still in flight
+    kernel.attach_profiler(Profiler(sample_every=1))
+    kernel(a, a)                  # a hit the profiler reads: waits for it
+    kernel.attach_profiler(None)
+    kernel(a, a)                  # a hit with nothing in flight
     launches = [e for e in _spans(tr, "launch") if e["name"] == "launch"]
-    assert len(launches) == 2
+    assert len(launches) == 4
     events = _spans(tr, "launch.")
-    phases = ["launch.select", "launch.build", "launch.dispatch",
-              "launch.wait"]
-    first, second = launches
-    assert _children(events, first, "launch.") == [
-        "launch.select", "launch.build", "launch.compile",
-        "launch.dispatch", "launch.wait"]
-    assert _children(events, second, "launch.") == phases
-    assert first["args"]["cached"] is False and second["args"]["cached"]
-    assert set(second["args"]) == {"kernel", "tier", "scenario", "cached",
-                                   "compile_us", "launch_us"}
+    assert [_children(events, e, "launch.") for e in launches] == [
+        ["launch.select", "launch.build", "launch.compile",
+         "launch.dispatch"],
+        ["launch.select", "launch.build", "launch.wait", "launch.dispatch"],
+        ["launch.select", "launch.build", "launch.wait", "launch.dispatch",
+         "launch.wait"],
+        ["launch.select", "launch.build", "launch.dispatch"]]
+    assert [e["args"]["cached"] for e in launches] == [False, True, True,
+                                                       True]
+    assert [e["args"]["waited"] for e in launches] == [False, False, True,
+                                                       False]
+    assert set(launches[1]["args"]) == {
+        "kernel", "tier", "scenario", "cached", "waited", "compile_us",
+        "launch_us"}
+    for e, st in zip(launches, kernel.stats):
+        assert st.launch_s == st.dispatch_s + st.wait_s > 0
+        assert e["args"]["launch_us"] == round(st.launch_s * 1e6, 3)
+    assert not hasattr(kernel.stats[-1], "load_s")
+
+
+@pytest.fixture()
+def waits(monkeypatch):
+    """For each ``jax.block_until_ready`` call, the ids of the arrays it
+    waited for."""
+    calls = []
+    real = jax.block_until_ready
+
+    def counted(x):
+        calls.append(_ids(x))
+        return real(x)
+    monkeypatch.setattr(jax, "block_until_ready", counted)
+    return calls
+
+
+def _ids(tree) -> set[int]:
+    return {id(x) for x in jax.tree_util.tree_leaves(tree)}
+
+
+def test_a_hit_with_no_latency_consumer_does_not_wait_for_its_result(
+        kernel, waits):
+    from repro.prof import Profiler
+    rng = np.random.default_rng(3)
+    a, b = (rng.standard_normal((64, 64)).astype(np.float32)
+            for _ in range(2))
+    first = kernel(a, b)
+    assert waits == []
+    got = kernel(a, b)
+    # it waited for the launch before it, which was still in flight
+    assert waits == [_ids(first)]
     st = kernel.stats[-1]
-    assert st.launch_s == st.dispatch_s + st.wait_s > 0
-    assert second["args"]["launch_us"] == round(st.launch_s * 1e6, 3)
-    assert not hasattr(st, "load_s")
+    assert st.cached and not st.waited
+    assert st.wait_s == 0.0 and st.launch_s == st.dispatch_s
+    kernel.attach_profiler(Profiler(sample_every=1))
+    want = kernel(a, b)
+    assert waits[1:] == [_ids(got), _ids(want)] and kernel.stats[-1].waited
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_each_kernel_keeps_one_launch_in_flight(tmp_path, waits):
+    from repro.core import WisdomKernel, get_kernel
+    k1, k2 = (WisdomKernel(get_kernel("matmul"), wisdom_dir=tmp_path,
+                           device_kind="tpu-v5e", backend="reference")
+              for _ in range(2))
+    a = np.ones((64, 64), np.float32)
+    outs = [k1(a, a), k2(a, a), k1(a, a), k1(a, a), k2(a, a)]
+    assert waits == [_ids(outs[i]) for i in (0, 2, 1)]
+
+
+def test_an_output_donated_before_the_next_launch_is_not_waited_for(
+        kernel, waits):
+    a = np.ones((64, 64), np.float32)
+    out = kernel(a, a)
+    jax.jit(lambda x: x * 2, donate_argnums=0)(out)
+    assert out.is_deleted()
+    np.testing.assert_array_equal(np.asarray(kernel(a, a)), a @ a)
+    assert waits == [set()]
+
+
+class _Tuner:
+    """The online tuner's launch hooks, recording the latency they read."""
+
+    def __init__(self):
+        self.launch_s = []
+
+    def before_launch(self, problem, dtype, meta, config, tier):
+        return None
+
+    def after_launch(self, problem, dtype, config, tier, launch_s):
+        self.launch_s.append(launch_s)
+
+
+@pytest.mark.parametrize("consumer", ["online", "profiler"])
+def test_a_latency_consumer_makes_the_launch_wait(kernel, waits, consumer):
+    from repro.prof import Profiler
+    a = np.ones((64, 64), np.float32)
+    kernel(a, a)
+    if consumer == "online":
+        tuner = _Tuner()
+        kernel.attach_online(tuner)
+        want = [True, True, True, True]
+    else:
+        # due on the first launch after attaching, then every second one
+        kernel.attach_profiler(Profiler(sample_every=2))
+        want = [True, False, True, False]
+    outs = [kernel(a, a) for _ in range(4)]
+    stats = kernel.stats[1:]
+    assert [s.waited for s in stats] == want
+    assert all(_ids(out) in waits for out, waited in zip(outs, want)
+               if waited)
+    for s in stats:
+        assert s.cached
+        assert s.launch_s == s.dispatch_s + s.wait_s
+        assert (s.wait_s > 0) == s.waited
+    if consumer == "online":
+        assert tuner.launch_s == [s.launch_s for s in stats]
+    else:
+        assert len(kernel.profiler.profiles) == 2
+
+
+def test_the_launch_wait_counter_counts_waited_and_async_launches(kernel):
+    from repro.obs.metrics import parse_series
+    from repro.prof import Profiler
+    a = np.ones((64, 64), np.float32)
+    reg, _ = runtime.enable()
+    for _ in range(3):
+        kernel(a, a)
+    kernel.attach_profiler(Profiler(sample_every=2))
+    for _ in range(3):
+        kernel(a, a)
+    got = {}
+    for key, n in reg.snapshot()["counters"].items():
+        name, labels = parse_series(key)
+        if name == "launch.wait":
+            got[(labels["kernel"], labels["outcome"])] = n
+    assert got == {("matmul", "async"): 4, ("matmul", "waited"): 2}
 
 
 def test_a_launch_that_raises_is_marked_and_not_counted(kernel, monkeypatch):

@@ -12,6 +12,7 @@ import pytest
 
 from repro.configs.microhh import Scenario
 from repro.core import WisdomKernel, get_device, get_kernel
+from repro.core.builder import args_meta
 from repro.tuner import CostModelEvaluator, tune_kernel, tune_random
 
 
@@ -93,6 +94,39 @@ def test_c5_first_launch_compiles_then_caches(wisdom_dir, small_fields):
     u2, v2, w2 = u[:8], v[:8], w[:8]
     k(u2, v2, w2, scal)
     assert not k.stats[2].cached
+
+
+@pytest.mark.parametrize("backend,maker", [("reference", "make_reference"),
+                                           ("interpret", "make")])
+def test_c5_a_cache_hit_builds_nothing(wisdom_dir, small_fields, monkeypatch,
+                                       backend, maker):
+    """The kernel is built on a miss only; a hit selects and dispatches."""
+    u, v, w, _, scal = small_fields
+    builder = get_kernel("advec_u")
+    k = WisdomKernel(builder, wisdom_dir=wisdom_dir, device_kind="tpu-v5e",
+                     backend=backend)
+    calls = {"_instantiate": 0, maker: 0}
+
+    def counted(obj, name):
+        real = getattr(obj, name)
+
+        def call(*a, **kw):
+            calls[name] += 1
+            return real(*a, **kw)
+        monkeypatch.setattr(obj, name, call)
+
+    counted(k, "_instantiate")
+    counted(builder, maker)
+    k(u, v, w, scal)
+    assert calls == {"_instantiate": 1, maker: 1}
+    k(u, v, w, scal)
+    k(u, v, w, scal)
+    assert calls == {"_instantiate": 1, maker: 1}
+    assert [s.cached for s in k.stats] == [False, True, True]
+    # prewarming a scenario already compiled builds nothing either
+    cfg = k.stats[0].config
+    assert k.prewarm(args_meta(u, v, w, scal), cfg) is False
+    assert calls == {"_instantiate": 1, maker: 1}
 
 
 @pytest.mark.parametrize("env_set", [True, False])
