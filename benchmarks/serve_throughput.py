@@ -61,8 +61,7 @@ def serve_mode(mode: str):
     for rid, (plen, mnew) in enumerate(WORKLOAD):
         eng.submit(Request(
             rid, rng.integers(0, cfg.vocab, size=plen, dtype=np.int32),
-            max_new_tokens=mnew,
-            scenario=f"tpu-v5e|{plen}x{mnew}|int32"))
+            max_new_tokens=mnew))
     return eng.run()
 
 

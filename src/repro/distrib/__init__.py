@@ -11,7 +11,7 @@ asset:
   (device, problem, dtype) scenario, provenance preserved as lineage;
 * :mod:`.sync`  — pluggable transports (directory, in-memory) with
   :class:`PushSync` (publish / promotion broadcast) and :class:`PullSync`
-  (periodic fleet pull, wired into ``ServeEngine``);
+  (periodic fleet pull, ticked by the serving host between runs);
 * :mod:`.cli`   — the ``python -m repro.wisdom`` operator tool
   (inspect/diff/merge/prune/validate/migrate).
 """

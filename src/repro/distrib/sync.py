@@ -11,9 +11,10 @@ via :class:`DirectoryTransport`, or an in-process dict via
   online promotion pipeline its ``broadcast`` hook so a confident winner
   leaves the machine the moment it is promoted;
 * :class:`PullSync` merges fleet wisdom into the local store and refreshes
-  attached ``WisdomKernel`` selection caches; its :meth:`PullSync.tick` is
-  cheap enough to call every decode step (``ServeEngine`` does), actually
-  pulling only every ``interval`` ticks.
+  attached ``WisdomKernel`` selection caches; its :meth:`PullSync.tick`
+  is cheap enough to call often (a serving host calls it between
+  ``ServeEngine.run`` calls), actually pulling only every ``interval``
+  ticks.
 
 Both directions go through the merge engine, so sync is idempotent,
 order-independent, and can only ever improve a store.
@@ -231,11 +232,11 @@ class PullSync:
         return report
 
     def tick(self) -> MergeReport | None:
-        """Serving-loop hook: pulls on every ``interval``-th call (first
+        """Serving-host hook: pulls on every ``interval``-th call (first
         call included, so a fresh engine starts from fleet wisdom).
 
-        Failure-isolated: a raising transport must not kill the decode
-        step that sponsored the tick, so errors are swallowed here —
+        Failure-isolated: a raising transport must not kill the serving
+        host that ticks it, so errors are swallowed here —
         counted in ``failures``, the exception kept in ``last_error`` —
         and the previously served wisdom stays in effect until the next
         due tick retries. Callers who need the error should call

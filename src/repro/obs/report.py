@@ -282,8 +282,7 @@ def render_report(snapshot: dict, top: int = 10) -> str:
     done = _counter_total(snapshot, "serve.requests_completed")
     if done:
         activity.append(f"requests-completed={_fmt_n(done)}")
-    sync_fail = (_counter_total(snapshot, "serve.sync_tick", outcome="failed")
-                 + _counter_total(snapshot, "sync.failures"))
+    sync_fail = _counter_total(snapshot, "sync.failures")
     activity.append(f"sync-failures={_fmt_n(sync_fail)}")
     trials = _counter_total(snapshot, "online.trials")
     promos = _counter_total(snapshot, "online.promotions",

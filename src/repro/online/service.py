@@ -199,8 +199,9 @@ class OnlineTuner:
         """Record a trace-time selection (launch running inside an outer
         jit). One trace stands for a whole execution stream, so a missed
         traced selection makes the scenario hot immediately; the actual
-        tuning work then runs through :meth:`tick` (the host's decode/train
-        loop sponsors it), not through launch hooks."""
+        tuning work then runs through :meth:`tick` (ticked by the serving
+        host between runs, or by the train loop), not through launch
+        hooks."""
         st = self.tracker.observe(self.kernel.device_kind, problem, dtype,
                                   tier,
                                   weight=self.tracker.activation_threshold)
@@ -215,8 +216,9 @@ class OnlineTuner:
 
     def tick(self) -> int:
         """Advance screening/promotion for every active scenario under one
-        launch's worth of budget — for hosts (serving decode loop, train
-        warmup) that want tuning progress between kernel launches.
+        launch's worth of budget — for hosts (a serving host between
+        ``ServeEngine.run`` calls, train warmup) that want tuning progress
+        between kernel launches.
 
         Scenarios whose demand was observed only at trace time (launches
         running inside an outer jit) can never receive live trial

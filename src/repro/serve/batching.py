@@ -1,18 +1,12 @@
-"""Continuous batching scheduler with scenario-bucketed admission.
+"""Continuous batching scheduler: one FIFO admission queue.
 
 Fixed-slot batching (the KV cache is a static (B, S) arena under jit):
 requests occupy slots; a finished request frees its slot immediately and a
 queued request is admitted on the next step with a per-slot prefill.
 Admission control rejects requests longer than the arena.
 
-Queued requests are *bucketed by tuned scenario key* (the
-``core/scenario.py`` ``format_key`` strings wisdom records are selected
-by): admission drains one bucket FIFO before switching to the oldest
-remaining bucket, so the slots running concurrently share a scenario and
-each decode launch lands on a wisdom-exact config instead of forcing a
-shape-miss fallback. Within a bucket, admission order is submission order
-— never reordered, property-tested in ``tests/test_serve_batching.py``.
-
+Queued requests wait in one queue and are admitted in submission order —
+never reordered, property-tested in ``tests/test_serve_batching.py``.
 Token-level callers (``ServeEngine`` in token mode) pass their arena
 write cursor to :meth:`ContinuousBatcher.admit`: a request that no longer
 fits the remaining arena blocks admission head-of-line (no skipping —
@@ -32,29 +26,25 @@ class Slot:
     pos: int = 0                  # tokens generated so far (incl. prompt)
     max_pos: int = 0              # stop position
     active: bool = False
-    scenario: str = ""            # bucket the request was admitted from
     start: int = 0                # arena write cursor at admission
 
 
 @dataclass
 class QueuedRequest:
-    """One queued submission: identity, lengths, its scenario bucket, and
-    a global arrival sequence number (the FIFO evidence — ``queue`` sorts
-    on it, and the stress tests assert per-bucket admission follows it)."""
+    """One queued submission: identity and lengths."""
     request_id: int
     prompt_len: int
     max_new_tokens: int
-    scenario: str
-    seq: int
 
 
 class ContinuousBatcher:
     """Slot scheduler for continuous batching (see module docstring).
 
     Bookkeeping only — owns no model or cache. ``submit`` enqueues (or
-    rejects oversize), ``admit`` fills free slots from the scenario
-    buckets, ``step``/``advance`` move slots forward and free finished
-    ones. ``finished``/``rejected`` are append-only audit logs."""
+    rejects oversize), ``admit`` fills free slots from the queue,
+    ``step``/``advance`` move slots forward and free finished ones — the
+    only place a slot is freed. ``finished``/``rejected`` are append-only
+    audit logs (``finished`` in completion order)."""
 
     def __init__(self, n_slots: int, max_seq: int):
         self.n_slots = n_slots
@@ -62,61 +52,35 @@ class ContinuousBatcher:
         self.slots = [Slot() for _ in range(n_slots)]
         self.finished: list[int] = []
         self.rejected: list[int] = []
-        # scenario key -> FIFO of queued requests. A dict preserves
-        # insertion order; _next() picks by oldest head, not dict order.
-        self.buckets: dict[str, deque[QueuedRequest]] = {}
-        #: Bucket admissions are currently drawing from (sticky until it
-        #: empties, so slots keep sharing a scenario).
-        self.active_scenario: str | None = None
-        #: Times admission moved to a different bucket (telemetry: each
-        #: switch is a likely config/compile change for the next launch).
-        self.scenario_switches = 0
-        self._arrivals = 0
+        self._queue: deque[QueuedRequest] = deque()
 
     # -- intake --------------------------------------------------------------
 
     def submit(self, request_id: int, prompt_len: int,
-               max_new_tokens: int, scenario: str = "") -> bool:
-        """Enqueue a request into its scenario bucket; False (and logged
-        in ``rejected``) if it cannot ever fit the arena."""
+               max_new_tokens: int) -> bool:
+        """Enqueue a request; False (and logged in ``rejected``) if it
+        cannot ever fit the arena."""
         if prompt_len + max_new_tokens > self.max_seq:
             self.rejected.append(request_id)
             return False
-        bucket = self.buckets.setdefault(str(scenario), deque())
-        bucket.append(QueuedRequest(request_id, prompt_len, max_new_tokens,
-                                    str(scenario), self._arrivals))
-        self._arrivals += 1
+        self._queue.append(QueuedRequest(request_id, prompt_len,
+                                         max_new_tokens))
         return True
 
     # -- admission -----------------------------------------------------------
 
-    def _oldest_bucket(self) -> str | None:
-        live = [(q[0].seq, name) for name, q in self.buckets.items() if q]
-        if not live:
-            return None
-        return min(live)[1]
-
     def _next(self, arena_pos: int) -> QueuedRequest | None:
-        """Pop the next admissible request: stay on the active bucket
-        until it drains, then switch to the bucket whose head arrived
-        first. Head-of-line within the bucket: if the head does not fit
-        the remaining arena, nothing is admitted (no skipping)."""
-        name = self.active_scenario
-        if name is None or not self.buckets.get(name):
-            name = self._oldest_bucket()
-            if name is None:
-                return None
-            if self.active_scenario is not None \
-                    and name != self.active_scenario:
-                self.scenario_switches += 1
-            self.active_scenario = name
-        head = self.buckets[name][0]
+        """Pop the head of the queue, head-of-line: if it does not fit the
+        remaining arena, nothing is admitted (no skipping)."""
+        if not self._queue:
+            return None
+        head = self._queue[0]
         if arena_pos + head.prompt_len + head.max_new_tokens > self.max_seq:
             return None
-        return self.buckets[name].popleft()
+        return self._queue.popleft()
 
     def admit(self, arena_pos: int = 0) -> list[tuple[int, int, int]]:
-        """Fill free slots from the scenario buckets.
+        """Fill free slots from the queue.
 
         ``arena_pos`` is the caller's arena write cursor (token-level
         engines); a request needing more arena than remains blocks
@@ -132,8 +96,7 @@ class ContinuousBatcher:
             self.slots[i] = Slot(request_id=nxt.request_id,
                                  pos=nxt.prompt_len,
                                  max_pos=nxt.prompt_len + nxt.max_new_tokens,
-                                 active=True, scenario=nxt.scenario,
-                                 start=arena_pos)
+                                 active=True, start=arena_pos)
             admitted.append((i, nxt.request_id, nxt.prompt_len))
         return admitted
 
@@ -141,8 +104,8 @@ class ContinuousBatcher:
 
     def advance(self, slot_idx: int) -> int | None:
         """Advance one slot by one token; frees the slot and returns the
-        request id when it finishes (else None). Token-level engines call
-        this per slot per generated token — slots still being prefilled
+        request id when it finishes (else None). Engines call this per
+        slot per generated token — slots still being fed their prompt
         are simply not advanced that step."""
         s = self.slots[slot_idx]
         if not s.active:
@@ -157,8 +120,8 @@ class ContinuousBatcher:
         return None
 
     def step(self) -> list[int]:
-        """Advance every active slot one token (lock-step/cohort view);
-        returns freed request ids."""
+        """Advance every active slot one token (lock-step view); returns
+        freed request ids."""
         freed = []
         for i, s in enumerate(self.slots):
             if s.active:
@@ -171,15 +134,12 @@ class ContinuousBatcher:
 
     @property
     def queue(self) -> list[QueuedRequest]:
-        """All queued requests in global arrival order (flattened view
-        over the scenario buckets; read-only snapshot)."""
-        out = [r for bucket in self.buckets.values() for r in bucket]
-        out.sort(key=lambda r: r.seq)
-        return out
+        """Queued requests in submission order (read-only snapshot)."""
+        return list(self._queue)
 
     @property
     def queue_depth(self) -> int:
-        return sum(len(bucket) for bucket in self.buckets.values())
+        return len(self._queue)
 
     @property
     def active_slots(self) -> int:

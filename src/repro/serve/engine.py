@@ -9,11 +9,10 @@ Two scheduling modes over the same static (n_slots, max_seq) KV arena
   positions): the arena keeps one physical write
   cursor (``cache["pos"]``) but each slot owns a logical window
   ``[start[b], pos]`` carried in ``cache["start"]``. A request that
-  finishes frees its slot *mid-stream*; the next queued request — picked
-  from the batcher's scenario buckets so concurrent slots share a tuned
-  scenario — is admitted at the current cursor and fed its prompt
-  per-slot while other slots keep generating. When the arena runs out,
-  the engine opens a fresh arena generation (new cache) and continues.
+  finishes frees its slot *mid-stream*; the head of the batcher's queue
+  is admitted at the current cursor and fed its prompt per-slot while
+  other slots keep generating. When the arena runs out, the engine
+  opens a fresh arena generation (new cache) and continues.
   Stale K/V from a slot's previous occupant sits below ``start`` and is
   masked out of attention entirely (zeroing would still leak softmax
   weight), which also keeps rotary phases correct: only relative
@@ -57,16 +56,10 @@ from .batching import ContinuousBatcher
 
 @dataclass
 class Request:
-    """One generation request: prompt tokens in, sampled tokens out.
-
-    ``scenario`` is an optional tuned-scenario key (``core/scenario.py``
-    ``format_key`` string, e.g. ``"tpu-v5e|256x256|float32"``): the
-    batcher buckets admission by it so slots running concurrently share
-    a wisdom-exact configuration. Empty string = unbucketed."""
+    """One generation request: prompt tokens in, sampled tokens out."""
     request_id: int
     prompt: np.ndarray            # (prompt_len,) int32
     max_new_tokens: int = 16
-    scenario: str = ""
     tokens: list = field(default_factory=list)   # generated
 
 
@@ -89,12 +82,9 @@ class ServeReport:
     cohorts: int = 0
     requests_completed: int = 0
     steps: int = 0
-    sync_pulls: int = 0
-    sync_failures: int = 0
     mode: str = "cohort"
     occupancy: float = 0.0
     inflight_admissions: int = 0
-    scenario_switches: int = 0
 
     def __getitem__(self, request_id: int) -> list[int]:
         return self.outputs[request_id]
@@ -120,11 +110,9 @@ class ServeReport:
     def to_json(self) -> dict:
         return {"cohorts": self.cohorts,
                 "requests_completed": self.requests_completed,
-                "steps": self.steps, "sync_pulls": self.sync_pulls,
-                "sync_failures": self.sync_failures, "mode": self.mode,
+                "steps": self.steps, "mode": self.mode,
                 "occupancy": self.occupancy,
-                "inflight_admissions": self.inflight_admissions,
-                "scenario_switches": self.scenario_switches}
+                "inflight_admissions": self.inflight_admissions}
 
 
 class ServeEngine:
@@ -134,10 +122,16 @@ class ServeEngine:
     returned :class:`ServeReport` maps request ids to generated tokens
     plus run statistics. ``mode`` is ``"auto"`` (token-level when the
     model supports per-slot attention windows, else cohort), ``"token"``
-    or ``"cohort"``. Optional collaborators: online autotuners
-    (``repro.online``), fleet wisdom sync (``repro.distrib.PullSync``)
-    and a decode-step roofline profiler (``repro.prof``) all tick once
-    per decode step in either mode.
+    or ``"cohort"``. One optional collaborator, ``profiler``, is the step
+    clock: it is bound to the engine and asked once per decode step, in
+    either mode, whether to time that step (``bind``/``due``/``on_step``,
+    as ``repro.prof.StepProfiler`` and the benchmark's step clock
+    implement them).
+
+    The decode step is traced once per engine, so a change to wisdom
+    never reaches it: callers tick online tuners (``OnlineTuner.tick``)
+    and fleet sync (``PullSync.tick``) themselves, between :meth:`run`
+    calls, for the next engine's trace.
 
     Example::
 
@@ -149,8 +143,7 @@ class ServeEngine:
 
     def __init__(self, model, params, n_slots: int = 4,
                  max_seq: int = 512, temperature: float = 0.0,
-                 rng_seed: int = 0, online=None, sync=None,
-                 profiler=None, mode: str = "auto"):
+                 rng_seed: int = 0, profiler=None, mode: str = "auto"):
         self.model = model
         self.params = params
         self.n_slots = n_slots
@@ -181,24 +174,6 @@ class ServeEngine:
         self.steps_run = 0
         self._useful_slot_steps = 0
         self._inflight_admissions = 0
-        # Optional online autotuner(s) (repro.online.OnlineTuner): each
-        # decode step sponsors one launch-budget slice of background tuning
-        # via tick(). Kernels launched inside the jitted decode report
-        # their scenario at trace time (observe_traced); tick() screens
-        # them and — under the cost-model objective — resolves their
-        # bracket too, since live trials can't be interleaved into a
-        # compiled graph. Promotions land in wisdom for the next trace.
-        if online is None:
-            online = []
-        elif not isinstance(online, (list, tuple)):
-            online = [online]
-        self.online = list(online)
-        # Optional fleet wisdom pull (repro.distrib.PullSync): tick() is
-        # called once per decode step and actually pulls every
-        # sync.interval ticks, merging fleet wisdom into the local store
-        # and hot-refreshing attached kernels — this host serves with the
-        # whole fleet's tuning results, not just its own.
-        self.sync = sync
         # Optional decode-step profiler (repro.prof.StepProfiler): every
         # Nth step is timed to a blocking boundary and recorded as a
         # "serve.decode" roofline profile (params streamed from HBM per
@@ -206,19 +181,12 @@ class ServeEngine:
         # how much, and drifts against the run's first sampled step).
         # Unsampled steps pay one None check — no extra block/clock.
         self.profiler = profiler
-        if profiler is None:
-            from repro.prof.profiler import (StepProfiler,
-                                             process_profiler)
-            ambient = process_profiler()
-            if ambient is not None:
-                self.profiler = StepProfiler(ambient)
-        if self.profiler is not None:
-            self.profiler.bind(params, n_slots, max_seq)
+        if profiler is not None:
+            profiler.bind(params, n_slots, max_seq)
 
     def submit(self, req: Request) -> bool:
         ok = self.batcher.submit(req.request_id, len(req.prompt),
-                                 req.max_new_tokens,
-                                 scenario=req.scenario)
+                                 req.max_new_tokens)
         if ok:
             self._requests[req.request_id] = req
         return ok
@@ -282,66 +250,47 @@ class ServeEngine:
                 out[name] += int(self.cache[name])
         return out
 
-    def _tick_services(self, m) -> None:
-        """Per-decode-step collaborator ticks (both modes)."""
+    def _count_step(self, m) -> None:
+        """The decode-step counter (both modes), under the ``serve.tick``
+        span that the benchmark's span list still names."""
         with obs.span("serve.tick", cat="serve"):
             if m is not None:
                 m.counter("serve.decode_steps").inc()
-            for svc in self.online:
-                svc.tick()
-            if self.sync is not None:
-                fails_before = self.sync.failures
-                pulled = self.sync.tick()
-                if m is not None:
-                    if pulled is not None:
-                        outcome = "pulled"
-                    elif self.sync.failures > fails_before:
-                        outcome = "failed"
-                    else:
-                        outcome = "skipped"
-                    m.counter("serve.sync_tick", outcome=outcome).inc()
 
     # -- cohort mode ---------------------------------------------------------
 
     def _run_cohort(self, members: list[tuple[int, int, int]]) -> None:
         """members: [(slot, request_id, prompt_len)]. Fresh cache; decode
-        in lock-step until every member has its tokens."""
+        in lock-step until the batcher has freed every member's slot."""
+        b = self.batcher
         cache = self._new_cache()
         reqs = {slot: self._requests[rid] for slot, rid, _ in members}
-        done = {slot: False for slot in reqs}
         next_tok = np.zeros((self.n_slots, 1), np.int32)
         for slot, req in reqs.items():
             next_tok[slot, 0] = req.prompt[0]
-        t = 0
-        while not all(done.values()) and t < self.max_seq - 1:
+        for t in range(self.max_seq - 1):
+            live = [slot for slot in reqs if b.slots[slot].active]
+            if not live:
+                break
             with obs.span("serve.step", cat="serve"):
                 m = obs.metrics()
-                live = sum(1 for v in done.values() if not v)
-                self._useful_slot_steps += live
+                self._useful_slot_steps += len(live)
                 if m is not None:
                     m.histogram("batch.occupancy",
-                                UNIT_BUCKETS).observe(live / self.n_slots)
+                                UNIT_BUCKETS).observe(len(live)
+                                                      / self.n_slots)
                 logits, cache = self._decode_once(cache, next_tok)
-                self._tick_services(m)
+                self._count_step(m)
                 sampled = self._sample_logits(logits)
                 with obs.span("serve.advance", cat="serve"):
-                    for slot, req in reqs.items():
-                        if done[slot]:
-                            continue
+                    for slot in live:
+                        req = reqs[slot]
                         if t + 1 < len(req.prompt):
                             next_tok[slot, 0] = req.prompt[t + 1]  # feeding
-                        else:
-                            req.tokens.append(int(sampled[slot]))
-                            next_tok[slot, 0] = sampled[slot]
-                            if len(req.tokens) >= req.max_new_tokens:
-                                done[slot] = True
-            t += 1
-        # release slots
-        for slot, rid, _ in members:
-            s = self.batcher.slots[slot]
-            self.batcher.finished.append(rid)
-            s.active = False
-            s.request_id = None
+                            continue
+                        req.tokens.append(int(sampled[slot]))
+                        next_tok[slot, 0] = sampled[slot]
+                        b.advance(slot)
         m = obs.metrics()
         if m is not None:
             m.counter("serve.requests_completed").inc(len(members))
@@ -418,7 +367,7 @@ class ServeEngine:
                                                       / self.n_slots)
                 logits, cache = self._decode_once(cache, next_tok, starts)
                 arena_pos += 1
-                self._tick_services(m)
+                self._count_step(m)
                 sampled = self._sample_logits(logits)
                 with obs.span("serve.advance", cat="serve"):
                     completed = 0
@@ -454,9 +403,6 @@ class ServeEngine:
         done0 = len(self.batcher.finished)
         useful0 = self._useful_slot_steps
         inflight0 = self._inflight_admissions
-        switches0 = self.batcher.scenario_switches
-        pulls0 = self.sync.pulls if self.sync is not None else 0
-        fails0 = self.sync.failures if self.sync is not None else 0
         if self.mode == "token":
             cohorts = self._run_token_mode(max_cohorts)
         else:
@@ -468,13 +414,7 @@ class ServeEngine:
             cohorts=cohorts,
             requests_completed=len(self.batcher.finished) - done0,
             steps=steps,
-            sync_pulls=(self.sync.pulls - pulls0
-                        if self.sync is not None else 0),
-            sync_failures=(self.sync.failures - fails0
-                           if self.sync is not None else 0),
             mode=self.mode,
             occupancy=(round(useful / (steps * self.n_slots), 4)
                        if steps else 0.0),
-            inflight_admissions=self._inflight_admissions - inflight0,
-            scenario_switches=(self.batcher.scenario_switches
-                               - switches0))
+            inflight_admissions=self._inflight_admissions - inflight0)

@@ -381,66 +381,69 @@ def test_tick_swallows_transport_failure_and_recovers(tmp_path):
     assert local.load("k").records[0].config == {"block": 4}
 
 
-def test_serve_engine_survives_sync_failure_mid_pull(tmp_path):
-    """ServeEngine end to end: the transport raising mid-pull must not
-    kill the cohort, and the engine keeps serving from the wisdom it
-    already had (no partial store state)."""
+@pytest.mark.parametrize("transport_fails", [False, True],
+                         ids=["pull", "transport-fails-mid-pull"])
+def test_pull_sync_between_runs_reaches_the_next_trace(tmp_path,
+                                                       transport_fails):
+    """The engine traces its decode step once, so fleet wisdom is pulled
+    between ``run()`` calls: a pull that lands refreshes the attached
+    kernel for the next trace, a transport that dies mid-pull leaves the
+    local store byte-identical, and either way the next run serves."""
     import jax.numpy as jnp
+    from repro.core import WisdomKernel, get_kernel, load_builtin_kernels
     from repro.serve.engine import Request, ServeEngine
+
+    load_builtin_kernels()
+    problem = (8, 8, 8)
+    default = get_kernel("matmul").default_config()
+    local = WisdomStore(tmp_path / "local")
+    local.save(Wisdom("matmul", [rec(problem=problem, score=100.0,
+                                     config=dict(default, block_m=128))]))
+    kernel = WisdomKernel(get_kernel("matmul"), wisdom_dir=local.root,
+                          device_kind="tpu-v5e", backend="reference")
 
     class TinyLM:
         def init_cache(self, n_slots, max_seq):
             return {"pos": jnp.zeros((), jnp.int32)}
 
         def decode_step(self, params, cache, tok):
-            return jnp.zeros((tok.shape[0], 1, 8), jnp.float32), cache
+            h = kernel(params["w"], params["w"])       # a traced launch
+            return jnp.broadcast_to(h[:1], (tok.shape[0], 1, 8)), cache
 
-    transport = FlakyTransport(fail_on="bbb")
+    transport = FlakyTransport(fail_on="matmul")
+    if not transport_fails:
+        transport.heal()
     transport.publish("aaa", Wisdom("aaa", [rec(config={"block": 2})])
                       .to_doc())
-    transport.publish("bbb", Wisdom("bbb", [rec(config={"block": 3})])
-                      .to_doc())
-    local = WisdomStore(tmp_path / "local")
-    before = Wisdom("aaa", [rec(score=1.0, config={"block": 8})])
-    local.save(before)
-    before_bytes = json.dumps(local.load("aaa").to_doc(), sort_keys=True)
+    transport.publish("matmul", Wisdom("matmul", [rec(
+        problem=problem, score=10.0,
+        config=dict(default, block_m=256))]).to_doc())
+    sync = PullSync(local, transport, kernels=[kernel])
 
-    sync = PullSync(local, transport, interval=2)
-    eng = ServeEngine(TinyLM(), params={}, n_slots=1, max_seq=16, sync=sync)
+    eng = ServeEngine(TinyLM(), params={"w": jnp.ones((8, 8), jnp.float32)},
+                      n_slots=1, max_seq=16)
     assert eng.submit(Request(0, np.array([1, 2], np.int32),
                               max_new_tokens=3))
+    assert len(eng.run()[0]) == 3
+    assert kernel.tier_counts == {"exact": 1}          # traced once
+    before = {f.name: f.read_bytes() for f in local.root.iterdir()}
+
+    pulled = sync.tick()                               # between runs
+    cfg, tier = kernel.select_config(problem, "float32")
+    if transport_fails:
+        assert pulled is None and sync.failures == 1
+        assert {f.name: f.read_bytes()
+                for f in local.root.iterdir()} == before
+        assert cfg["block_m"] == 128
+    else:
+        assert pulled is not None and sync.pulls == 1
+        assert local.kernels() == ["aaa", "matmul"]
+        assert (cfg["block_m"], tier) == (256, "exact")  # the next trace's
+
+    assert eng.submit(Request(1, np.array([3], np.int32), max_new_tokens=2))
     out = eng.run()
-    assert out[0] and eng.steps_run > 0        # serving completed
-    assert sync.failures > 0
-    # no partial state: neither kernel changed under the engine
-    assert local.kernels() == ["aaa"]
-    assert json.dumps(local.load("aaa").to_doc(),
-                      sort_keys=True) == before_bytes
-
-
-def test_serve_engine_ticks_sync(tmp_path):
-    import jax.numpy as jnp
-    from repro.serve.engine import Request, ServeEngine
-
-    class TinyLM:
-        def init_cache(self, n_slots, max_seq):
-            return {"pos": jnp.zeros((), jnp.int32)}
-
-        def decode_step(self, params, cache, tok):
-            return jnp.zeros((tok.shape[0], 1, 8), jnp.float32), cache
-
-    transport = MemoryTransport()
-    PushSync(store_with(tmp_path / "fleet", rec(config={"block": 6})),
-             transport).push()
-    local = WisdomStore(tmp_path / "local")
-    sync = PullSync(local, transport, interval=2)
-    eng = ServeEngine(TinyLM(), params={}, n_slots=1, max_seq=16, sync=sync)
-    assert eng.submit(Request(0, np.array([1, 2], np.int32),
-                              max_new_tokens=3))
-    eng.run()
-    assert eng.steps_run > 0
-    assert sync.pulls == (eng.steps_run + 1) // 2
-    assert local.load("k").records[0].config == {"block": 6}
+    assert out.requests_completed == 1 and len(out[1]) == 2
+    assert kernel.tier_counts == {"exact": 1}          # no retrace
 
 
 def test_promotion_broadcast_hook(tmp_path, wisdom_dir):

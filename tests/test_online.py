@@ -322,27 +322,6 @@ def test_train_step_ticks_online_during_warmup():
     assert svc.ticks == 2                # only the warmup steps sponsor work
 
 
-def test_serve_engine_ticks_online_each_decode_step():
-    from repro.serve.engine import Request, ServeEngine
-
-    class TinyLM:
-        def init_cache(self, n_slots, max_seq):
-            return {"pos": jnp.zeros((), jnp.int32)}
-
-        def decode_step(self, params, cache, tok):
-            logits = jnp.zeros((tok.shape[0], 1, 8), jnp.float32)
-            return logits, cache
-
-    svc = _FakeTuner()
-    eng = ServeEngine(TinyLM(), params={}, n_slots=2, max_seq=32,
-                      online=svc)
-    assert eng.submit(Request(0, np.array([1, 2], np.int32),
-                              max_new_tokens=3))
-    eng.run()
-    assert eng.steps_run > 0
-    assert svc.ticks == eng.steps_run
-
-
 # ------------------------------- env plumbing --------------------------------
 
 def test_env_auto_attach(monkeypatch, wisdom_dir):

@@ -53,33 +53,14 @@ def test_every_accepted_request_eventually_finishes(data):
     assert sorted(b.finished) == sorted(accepted)
 
 
-def test_scenario_bucketed_fifo_admission():
-    """Admission drains one scenario bucket before switching, and within
-    a bucket it is strictly FIFO (ISSUE 9: co-scheduled slots share a
-    tuned scenario so launches stay wisdom-exact)."""
-    b = ContinuousBatcher(n_slots=2, max_seq=64)
-    # interleaved submission across two scenarios
-    b.submit(0, 4, 4, scenario="A")
-    b.submit(1, 4, 4, scenario="B")
-    b.submit(2, 4, 4, scenario="A")
-    b.submit(3, 4, 4, scenario="B")
-    first = [rid for _, rid, _ in b.admit()]
-    assert first == [0, 2]              # bucket A drains first, in order
-    for _ in range(8):
-        b.step()
-    second = [rid for _, rid, _ in b.admit()]
-    assert second == [1, 3]             # then bucket B, in order
-    assert b.scenario_switches == 1
-
-
 def test_head_of_line_capacity_blocking():
-    """A head request that does not fit the remaining arena blocks its
-    bucket — later, smaller requests must not skip past it (skipping
+    """A head request that does not fit the remaining arena blocks the
+    queue — later, smaller requests must not skip past it (skipping
     would starve long requests)."""
     b = ContinuousBatcher(n_slots=2, max_seq=32)
-    b.submit(0, 16, 12, scenario="A")   # needs 28 columns
-    b.submit(1, 2, 2, scenario="A")     # would fit anywhere
-    assert b.admit(arena_pos=8) == []   # 8 + 28 > 32: head blocks bucket
+    b.submit(0, 16, 12)                 # needs 28 columns
+    b.submit(1, 2, 2)                   # would fit anywhere
+    assert b.admit(arena_pos=8) == []   # 8 + 28 > 32: head blocks queue
     admitted = [rid for _, rid, _ in b.admit(arena_pos=0)]
     assert admitted == [0, 1]           # fresh arena: FIFO order intact
 
@@ -90,13 +71,13 @@ def test_randomized_interleaving_conserves_requests(data):
     """Stress property: under any seeded interleaving of submit / admit /
     advance, every accepted request lives in exactly one of
     {queue, slot, finished}, no request is lost or duplicated, rejected
-    requests never reach a slot, and per-scenario admission order equals
-    submission order."""
+    requests never reach a slot, and admission order equals submission
+    order."""
     n_slots = data.draw(st.integers(1, 4))
     max_seq = data.draw(st.sampled_from([16, 32]))
     b = ContinuousBatcher(n_slots=n_slots, max_seq=max_seq)
     accepted, rejected, admitted_order = set(), set(), []
-    submitted_order = {}                # scenario -> [rid, ...]
+    submitted_order = []
     next_rid = 0
 
     def check_invariants():
@@ -110,34 +91,32 @@ def test_randomized_interleaving_conserves_requests(data):
         assert not (rejected & (queued | in_slots | finished))
         assert sum(s.active for s in b.slots) + sum(
             not s.active for s in b.slots) == n_slots    # slots conserved
+        assert [q.request_id for q in b.queue] == \
+            submitted_order[len(admitted_order):]        # queue is FIFO
 
     for _ in range(data.draw(st.integers(5, 40))):
         op = data.draw(st.sampled_from(["submit", "admit", "advance"]))
         if op == "submit":
             plen = data.draw(st.integers(1, 20))
             mnew = data.draw(st.integers(1, 20))
-            scen = data.draw(st.sampled_from(["A", "B", "C"]))
-            if b.submit(next_rid, plen, mnew, scenario=scen):
+            if b.submit(next_rid, plen, mnew):
                 accepted.add(next_rid)
-                submitted_order.setdefault(scen, []).append(next_rid)
+                submitted_order.append(next_rid)
             else:
                 rejected.add(next_rid)
             next_rid += 1
         elif op == "admit":
             pos = data.draw(st.integers(0, max_seq - 1))
             for _slot, rid, _plen in b.admit(arena_pos=pos):
-                admitted_order.append((b.slots[_slot].scenario, rid))
+                admitted_order.append(rid)
         else:
             active = [i for i, s in enumerate(b.slots) if s.active]
             if active:
                 b.advance(data.draw(st.sampled_from(active)))
         check_invariants()
 
-    # FIFO within each scenario bucket: the admitted rids of a scenario
-    # are a prefix of that scenario's submission order.
-    for scen, order in submitted_order.items():
-        got = [rid for s, rid in admitted_order if s == scen]
-        assert got == order[:len(got)]
+    # One FIFO: the admitted rids are a prefix of submission order.
+    assert admitted_order == submitted_order[:len(admitted_order)]
 
 
 class _StartAwareToyModel:
@@ -171,8 +150,7 @@ def test_mid_stream_admission_token_mode():
     lengths = {0: 2, 1: 9, 2: 3, 3: 5}  # short ones free mid-stream
     for rid, mnew in lengths.items():
         assert eng.submit(Request(rid, np.array([1, 2], np.int32),
-                                  max_new_tokens=mnew,
-                                  scenario="tpu-v5e|2x8|int32"))
+                                  max_new_tokens=mnew))
     out = eng.run()
     assert out.mode == "token"
     assert eng.batcher.done()
@@ -199,6 +177,74 @@ def test_cohort_mode_forced_on_token_capable_model():
     assert out.mode == "cohort"
     assert out.cohorts == 2 and out.inflight_admissions == 0
     assert {rid: len(out[rid]) for rid in range(3)} == {0: 2, 1: 2, 2: 2}
+
+
+class _TraceCountingToyModel(_StartAwareToyModel):
+    """Token-mode toy whose ``decode_step`` counts its Python traces."""
+
+    def __init__(self):
+        self.traces = 0
+
+    def decode_step(self, params, cache, tok):
+        self.traces += 1
+        return super().decode_step(params, cache, tok)
+
+
+def test_decode_step_traces_once_across_generations():
+    """The engine's jitted step has one static shape, so a run that
+    opens several arena generations and admits mid-stream still traces
+    it once: nothing changed between steps (wisdom included) can reach
+    the kernels selected in that trace."""
+    from repro.serve import Request, ServeEngine
+    model = _TraceCountingToyModel()
+    eng = ServeEngine(model, params={}, n_slots=2, max_seq=16)
+    for rid, mnew in enumerate([2, 9, 3, 5, 6, 4, 7]):
+        assert eng.submit(Request(rid, np.array([1, 2], np.int32),
+                                  max_new_tokens=mnew))
+    out = eng.run()
+    assert out.requests_completed == 7
+    assert out.cohorts >= 2 and out.inflight_admissions >= 1
+    assert model.traces == 1
+
+
+class _PosToyModel:
+    """Cohort-only toy (no ``decode_supports_start``): next token =
+    (tok + arena position) mod vocab, so outputs pin each token to the
+    step that made it."""
+
+    vocab = 13
+
+    def init_cache(self, n_slots, max_seq):
+        import jax.numpy as jnp
+        return {"pos": jnp.zeros((), jnp.int32)}
+
+    def decode_step(self, params, cache, tok):
+        import jax
+        nxt = (tok[:, 0] + cache["pos"]) % self.vocab
+        return (jax.nn.one_hot(nxt, self.vocab)[:, None],
+                {"pos": cache["pos"] + 1})
+
+
+def test_cohort_mode_frees_slots_through_the_batcher():
+    """Cohort mode advances each generated token through the batcher,
+    which alone frees a slot: afterwards every slot is free, each id is
+    finished once (in completion order), and the outputs and step count
+    are those of the lock-step loop that decided completion itself."""
+    from repro.serve import Request, ServeEngine
+    eng = ServeEngine(_PosToyModel(), params={}, n_slots=2, max_seq=16)
+    assert eng.mode == "cohort"
+    work = {0: ([1, 2, 3], 4), 1: ([5], 2), 2: ([7, 8], 5), 3: ([2], 1),
+            4: ([4, 6, 9], 3)}
+    for rid, (prompt, mnew) in work.items():
+        assert eng.submit(Request(rid, np.array(prompt, np.int32),
+                                  max_new_tokens=mnew))
+    out = eng.run()
+    assert not any(s.active for s in eng.batcher.slots)
+    assert eng.batcher.finished == [1, 0, 3, 2, 4]
+    assert dict(out.items()) == {0: [5, 8, 12, 4], 1: [5, 6],
+                                 2: [9, 11, 1, 5, 10], 3: [2],
+                                 4: [11, 1, 5]}
+    assert (out.cohorts, out.steps, out.occupancy) == (3, 17, 0.5882)
 
 
 def test_tuner_cli_end_to_end(tmp_path, monkeypatch, capture_dir,
