@@ -11,7 +11,9 @@ happens to be importable.
 
 :func:`lowering_kwargs` is that decision:
 
-* backend ``"tpu"``   -> Mosaic ``pltpu.CompilerParams(dimension_semantics)``
+* backend ``"tpu"``   -> Mosaic ``pltpu.CompilerParams(dimension_semantics)``,
+  with ``vmem_limit_bytes`` where the caller passes one (the stencil
+  kernels, whose large tiles outgrow Mosaic's 16 MiB scoped default)
 * backend ``"gpu"``   -> ``pltriton.CompilerParams(num_warps, num_stages)``
 * backend ``"cpu"``   -> no params (the interpreter takes none)
 * ``interpret=True``  -> no params, on any backend (the CI story: GPU
@@ -60,14 +62,15 @@ def _gpu_params(num_warps, num_stages):
 
 
 def lowering_kwargs(*, dimension_semantics=(), num_warps=None,
-                    num_stages=None, interpret: bool = False,
+                    num_stages=None, vmem_limit_bytes: int | None = None,
+                    interpret: bool = False,
                     backend: str | None = None) -> dict:
     """The ``pl.pallas_call`` kwargs the active backend accepts.
 
-    ``dimension_semantics`` feeds the Mosaic (TPU) params; ``num_warps``
-    and ``num_stages`` feed the Triton (GPU) params — callers pass both
-    sets and exactly one (or neither) is used. Returns ``{}`` under
-    ``interpret`` and on the CPU.
+    ``dimension_semantics`` and ``vmem_limit_bytes`` feed the Mosaic
+    (TPU) params; ``num_warps`` and ``num_stages`` feed the Triton (GPU)
+    params — callers pass both sets and exactly one (or neither) is
+    used. Returns ``{}`` under ``interpret`` and on the CPU.
 
     Example::
 
@@ -80,8 +83,10 @@ def lowering_kwargs(*, dimension_semantics=(), num_warps=None,
         return {}
     b = backend if backend is not None else active_backend()
     if b == "tpu" and dimension_semantics:
-        return {"compiler_params": pltpu.CompilerParams(
-            dimension_semantics=tuple(dimension_semantics))}
+        kw = {"dimension_semantics": tuple(dimension_semantics)}
+        if vmem_limit_bytes is not None:
+            kw["vmem_limit_bytes"] = int(vmem_limit_bytes)
+        return {"compiler_params": pltpu.CompilerParams(**kw)}
     if b == "gpu":
         return _gpu_params(num_warps, num_stages)
     return {}

@@ -4,13 +4,22 @@ TPU adaptation of the paper's MicroHH kernels: the X axis is the contiguous
 lane dimension and is kept whole inside each block; the grid tiles (Z, Y).
 Halos are passed as *separate side-slab refs* (each at least the stencil
 radius 3 thick) with wrapped (periodic) index maps — TPU has no overlapping
-BlockSpec reads, so each field arrives as five refs:
+BlockSpec reads, so a field shifted along both tiled axes arrives as five
+refs:
 
     center (bz, by, X), z-lo (4, by, X), z-hi, y-lo (bz, 8, X), y-hi
 
+and a field shifted along one of them brings only that axis's pair.
 Y is the sublane dimension of every block, so the y-halo is one full
 8-row sublane tile (``HALO_Y = 8``); Mosaic refuses a 4-row block there.
 Z is a leading (untiled) dimension and keeps the thinner ``HALO_Z = 4``.
+
+Every slab is read again by the neighbouring tile, so a field with both
+pairs is read ``1 + 2·HALO_Z/bz + 2·HALO_Y/by`` times per launch
+(:func:`halo_read_factor`): 3.0 at (bz, by) = (8, 16), 1.75 at (32, 32).
+Large tiles need more VMEM than Mosaic's scoped default (16 MiB), so each
+build asks for its tile's working set (:func:`stencil_vmem_limit`),
+capped at one v5e core's VMEM.
 
 The stencil math only ever shifts along one axis at a time, so no corner
 slabs are needed. Inside the kernel, per-axis extended views are assembled by
@@ -25,9 +34,20 @@ from typing import Callable
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.obs import runtime as obs
+
+from ._lowering import lowering_kwargs
+
 HALO_Z = 4            # z side-slab thickness (leading dim, untiled)
 HALO_Y = 8            # y side-slab thickness (one sublane tile)
 STENCIL_RADIUS = 3    # 5th-order interpolation reach
+#: The halo pairs of a field shifted along both tiled axes.
+BOTH = ("z", "y")
+#: Mosaic's scoped VMEM limit where a kernel asks for none.
+MOSAIC_DEFAULT_VMEM_BYTES = 16 * 2**20
+#: One TPU v5e core's VMEM: 128 MiB ("TPU v5 lite" in JAX's
+#: ``jax/_src/pallas/mosaic/tpu_info.py``, ``vmem_capacity_bytes``).
+V5E_VMEM_BYTES = 128 * 2**20
 
 
 def divides(a: int, b: int) -> bool:
@@ -47,8 +67,10 @@ def stencil_grid(problem: tuple[int, int, int], bz: int, by: int,
 
 
 def field_specs(problem: tuple[int, int, int], bz: int, by: int,
-                to_zy: Callable) -> list[pl.BlockSpec]:
-    """The five BlockSpecs (center, z-lo, z-hi, y-lo, y-hi) for one field."""
+                to_zy: Callable, halos: tuple[str, ...] = BOTH
+                ) -> list[pl.BlockSpec]:
+    """The BlockSpecs of one field: the center, then (z-lo, z-hi) where
+    ``"z"`` is in ``halos``, then (y-lo, y-hi) where ``"y"`` is."""
     nz, ny, nx = problem
     hz, hy = nz // HALO_Z, ny // HALO_Y
     rz, ry = bz // HALO_Z, by // HALO_Y
@@ -73,13 +95,34 @@ def field_specs(problem: tuple[int, int, int], bz: int, by: int,
         iz, iy = to_zy(a, b)
         return (iz, (iy * ry + ry) % hy, 0)
 
-    return [
-        pl.BlockSpec((bz, by, nx), center),
-        pl.BlockSpec((HALO_Z, by, nx), z_lo),
-        pl.BlockSpec((HALO_Z, by, nx), z_hi),
-        pl.BlockSpec((bz, HALO_Y, nx), y_lo),
-        pl.BlockSpec((bz, HALO_Y, nx), y_hi),
-    ]
+    specs = [pl.BlockSpec((bz, by, nx), center)]
+    if "z" in halos:
+        specs += [pl.BlockSpec((HALO_Z, by, nx), z_lo),
+                  pl.BlockSpec((HALO_Z, by, nx), z_hi)]
+    if "y" in halos:
+        specs += [pl.BlockSpec((bz, HALO_Y, nx), y_lo),
+                  pl.BlockSpec((bz, HALO_Y, nx), y_hi)]
+    return specs
+
+
+def n_refs(halos: tuple[str, ...]) -> int:
+    """Refs of one field: its center and two slabs per halo axis."""
+    return 1 + 2 * len(halos)
+
+
+def operands(fields, field_halos: list[tuple[str, ...]]) -> tuple:
+    """Each field repeated once per ref :func:`field_specs` gives it."""
+    return tuple(f for f, halos in zip(fields, field_halos)
+                 for _ in range(n_refs(halos)))
+
+
+def field_views(refs, field_halos: list[tuple[str, ...]]) -> list:
+    """One :class:`FieldView` per field from its consecutive refs."""
+    views, i = [], 0
+    for halos in field_halos:
+        views.append(FieldView.from_refs(refs[i:i + n_refs(halos)], halos))
+        i += n_refs(halos)
+    return views
 
 
 def out_spec(problem: tuple[int, int, int], bz: int, by: int,
@@ -95,21 +138,28 @@ def out_spec(problem: tuple[int, int, int], bz: int, by: int,
 
 class FieldView:
     """Kernel-side view of one field: center + per-axis extended arrays.
-    Takes plain (already loaded, already cast) block arrays."""
+    Takes plain (already loaded, already cast) block arrays; an axis
+    whose halo pair is ``None`` has no extended array and cannot be
+    shifted along."""
 
-    def __init__(self, center, zlo, zhi, ylo, yhi):
+    def __init__(self, center, zlo=None, zhi=None, ylo=None, yhi=None):
         self.c = center
-        self.ext_z = jnp.concatenate([zlo, self.c, zhi], axis=0)
-        self.ext_y = jnp.concatenate([ylo, self.c, yhi], axis=1)
+        self.ext_z = (None if zlo is None
+                      else jnp.concatenate([zlo, self.c, zhi], axis=0))
+        self.ext_y = (None if ylo is None
+                      else jnp.concatenate([ylo, self.c, yhi], axis=1))
         self.bz = self.c.shape[0]
         self.by = self.c.shape[1]
 
     @classmethod
-    def from_refs(cls, center_ref, zlo_ref, zhi_ref, ylo_ref, yhi_ref,
+    def from_refs(cls, refs, halos: tuple[str, ...] = BOTH,
                   dtype=jnp.float32):
-        return cls(*(r[...].astype(dtype)
-                     for r in (center_ref, zlo_ref, zhi_ref,
-                               ylo_ref, yhi_ref)))
+        """``refs`` in :func:`field_specs` order for the same ``halos``."""
+        arrays = [r[...].astype(dtype) for r in refs]
+        axes = [a for a in BOTH if a in halos]
+        pairs = dict(zip(axes, zip(arrays[1::2], arrays[2::2])))
+        return cls(arrays[0], *pairs.get("z", (None, None)),
+                   *pairs.get("y", (None, None)))
 
     def sx(self, s: int, rows: slice | None = None):
         """Shift along x by s cells (periodic over the full lane extent)."""
@@ -133,6 +183,57 @@ def check_blocks(problem: tuple[int, int, int], bz: int, by: int) -> bool:
             and bz <= nz and by <= ny
             and divides(bz, nz) and divides(by, ny)
             and divides(HALO_Z, nz) and divides(HALO_Y, ny))
+
+
+def halo_read_factor(bz: int, by: int,
+                     field_halos: list[tuple[str, ...]]) -> float:
+    """Mean HBM reads per input field of one launch at tile (bz, by):
+    each field's center once plus, per halo pair it brings, the slabs
+    that overlap a neighbouring tile (``2·HALO_Z/bz``, ``2·HALO_Y/by``)."""
+    per_axis = {"z": 2 * HALO_Z / bz, "y": 2 * HALO_Y / by}
+    return sum(1.0 + sum(per_axis[a] for a in halos)
+               for halos in field_halos) / len(field_halos)
+
+
+def stencil_vmem_limit(problem, bz: int, by: int, n_in_fields: int,
+                       n_out_fields: int) -> int:
+    """The scoped VMEM limit a stencil tile asks Mosaic for.
+
+    The double-buffered blocks of :func:`stencil_vmem_bytes` counted at
+    float32, plus the kernel's float32 copies of each input
+    (:class:`FieldView`'s center, ``ext_z`` and ``ext_y``), floored at
+    Mosaic's 16 MiB default and capped at one v5e core's VMEM.
+    """
+    nx = problem[2]
+    copies = n_in_fields * 4 * nx * (
+        bz * by + (bz + 2 * HALO_Z) * by + bz * (by + 2 * HALO_Y))
+    need = stencil_vmem_bytes(problem, bz, by, n_in_fields, n_out_fields,
+                              dtype_bytes=4) + copies
+    return int(min(V5E_VMEM_BYTES, max(MOSAIC_DEFAULT_VMEM_BYTES, need)))
+
+
+def stencil_lowering(kernel: str, config, problem,
+                     field_halos: list[tuple[str, ...]], n_out_fields: int,
+                     interpret: bool) -> dict:
+    """The ``pl.pallas_call`` kwargs of one stencil build, gated on the
+    active DeviceSpec.backend (not on whether pltpu merely imports):
+    Mosaic dimension semantics and the tile's VMEM limit reach only a
+    TPU lowering, Triton warps/stages only a GPU one, nothing the
+    interpreter. Sets the build's gauges (a compile-cache miss only):
+    ``stencil.halo_read_factor`` and ``stencil.vmem_limit_bytes``."""
+    bz, by = config["block_z"], config["block_y"]
+    limit = stencil_vmem_limit(problem, bz, by, len(field_halos),
+                               n_out_fields)
+    m = obs.metrics()
+    if m is not None:
+        m.gauge("stencil.halo_read_factor", kernel=kernel).set(
+            halo_read_factor(bz, by, field_halos))
+        m.gauge("stencil.vmem_limit_bytes", kernel=kernel).set(limit)
+    return lowering_kwargs(
+        dimension_semantics=(config["dim_semantics"],) * 2,
+        num_warps=8 if by >= 64 else 4,
+        num_stages=min(4, 1 + config["unroll_z"]),
+        vmem_limit_bytes=limit, interpret=interpret)
 
 
 def stencil_vmem_bytes(problem, bz: int, by: int, n_in_fields: int,

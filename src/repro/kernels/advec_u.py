@@ -25,21 +25,29 @@ from repro.core import KernelBuilder, Workload, register
 from repro.core.builder import probe_array
 
 from . import ref as _ref
-from ._lowering import lowering_kwargs
-from ._stencil_common import (FieldView, check_blocks, field_specs,
-                              out_spec, stencil_grid, stencil_hbm_bytes,
+from ._stencil_common import (BOTH, check_blocks, field_specs, field_views,
+                              operands, out_spec, stencil_grid,
+                              stencil_hbm_bytes, stencil_lowering,
                               stencil_vmem_bytes)
 
 
 builder = KernelBuilder("advec_u", source="repro.kernels.advec_u")
-# Default tiling compiles for a v5e at 256³ and 512³ in float32 and
-# bfloat16; bz=16, by=32 runs out of VMEM at 512³.
-builder.tune("block_z", (4, 8, 16, 32), default=8)
-builder.tune("block_y", (8, 16, 32, 64, 128, 256), default=16)
+# A field with both halo pairs is read 1 + 2·HALO_Z/bz + 2·HALO_Y/by
+# times (_stencil_common.halo_read_factor): 1.75 at the default (32, 32),
+# which tiles every grid down to 32³. Each build asks Mosaic for its
+# tile's VMEM (_stencil_common.stencil_vmem_limit), since tiles this
+# large outgrow the 16 MiB scoped default.
+builder.tune("block_z", (4, 8, 16, 32), default=32)
+builder.tune("block_y", (8, 16, 32, 64, 128, 256), default=32)
 builder.tune("traversal", ("zy", "yz"), default="zy")
 builder.tune("unroll_z", (1, 2, 4), default=1)
 builder.tune("dim_semantics", ("arbitrary", "parallel"), default="arbitrary")
 builder.restriction("block_z % unroll_z == 0")
+
+
+#: The halo pairs each input brings: the stencil shifts u along every
+#: axis, v along y alone and w along z alone.
+FIELD_HALOS = [BOTH, ("y",), ("z",)]
 
 
 @builder.problem_size
@@ -47,15 +55,9 @@ def _problem(u, v, w, scal):
     return tuple(int(d) for d in u.shape)
 
 
-def _kernel_body(unroll_z: int, refs):
-    (scal_ref,
-     u_c, u_zl, u_zh, u_yl, u_yh,
-     v_c, v_zl, v_zh, v_yl, v_yh,
-     w_c, w_zl, w_zh, w_yl, w_yh,
-     out_ref) = refs
-    fu = FieldView.from_refs(u_c, u_zl, u_zh, u_yl, u_yh)
-    fv = FieldView.from_refs(v_c, v_zl, v_zh, v_yl, v_yh)
-    fw = FieldView.from_refs(w_c, w_zl, w_zh, w_yl, w_yh)
+def _kernel_body(unroll_z: int, field_halos, refs):
+    scal_ref, out_ref = refs[0], refs[-1]
+    fu, fv, fw = field_views(refs[1:-1], field_halos)
     dxi = scal_ref[0, 0]
     dyi = scal_ref[0, 1]
     dzi = scal_ref[0, 2]
@@ -78,20 +80,16 @@ def _build(config, problem, meta, interpret: bool = False):
         raise ValueError(f"blocks ({bz},{by}) do not tile problem {problem}")
     grid, to_zy = stencil_grid(problem, bz, by, config["traversal"])
     scal_spec = pl.BlockSpec((1, 4), lambda a, b: (0, 0))
-    fspecs = field_specs(problem, bz, by, to_zy)
-    in_specs = [scal_spec] + fspecs * 3
-    # Compiler params are gated on the active DeviceSpec.backend (not on
-    # whether pltpu merely imports): Mosaic dimension_semantics on TPU,
-    # Triton warps/stages on GPU, nothing under interpret.
-    kwargs = lowering_kwargs(
-        dimension_semantics=(config["dim_semantics"],) * 2,
-        num_warps=8 if by >= 64 else 4,
-        num_stages=min(4, 1 + config["unroll_z"]),
-        interpret=interpret)
+    field_halos = FIELD_HALOS
+    in_specs = [scal_spec]
+    for halos in field_halos:
+        in_specs += field_specs(problem, bz, by, to_zy, halos)
+    kwargs = stencil_lowering("advec_u", config, problem, field_halos,
+                              n_out_fields=1, interpret=interpret)
 
     dtype = meta[0].dtype
     call = pl.pallas_call(
-        functools.partial(_pallas_entry, config["unroll_z"]),
+        functools.partial(_pallas_entry, config["unroll_z"], field_halos),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_spec(problem, bz, by, to_zy),
@@ -101,13 +99,13 @@ def _build(config, problem, meta, interpret: bool = False):
     )
 
     def run(u, v, w, scal):
-        return call(scal, u, u, u, u, u, v, v, v, v, v, w, w, w, w, w)
+        return call(scal, *operands((u, v, w), field_halos))
 
     return run
 
 
-def _pallas_entry(unroll_z, *refs):
-    _kernel_body(unroll_z, refs)
+def _pallas_entry(unroll_z, field_halos, *refs):
+    _kernel_body(unroll_z, field_halos, refs)
 
 
 builder.reference(_ref.advec_u_ref)

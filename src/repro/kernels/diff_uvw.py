@@ -21,17 +21,21 @@ from repro.core import KernelBuilder, Workload, register
 from repro.core.builder import probe_array
 
 from . import ref as _ref
-from ._lowering import lowering_kwargs
-from ._stencil_common import (FieldView, check_blocks, field_specs,
-                              out_spec, stencil_grid, stencil_hbm_bytes,
+from ._stencil_common import (BOTH, FieldView, check_blocks, field_specs,
+                              field_views, operands, out_spec, stencil_grid,
+                              stencil_hbm_bytes, stencil_lowering,
                               stencil_vmem_bytes)
 
 
 builder = KernelBuilder("diff_uvw", source="repro.kernels.diff_uvw")
-# Default tiling compiles for a v5e at 256³ and 512³ in float32 and
-# bfloat16; bz=16, by=32 runs out of VMEM at 512³.
-builder.tune("block_z", (4, 8, 16, 32), default=8)
-builder.tune("block_y", (8, 16, 32, 64, 128, 256), default=16)
+# Every input is shifted along all three axes, so each brings both halo
+# pairs and is read 1 + 2·HALO_Z/bz + 2·HALO_Y/by times
+# (_stencil_common.halo_read_factor): 1.75 at the default (32, 32), which
+# tiles every grid down to 32³. Each build asks Mosaic for its tile's
+# VMEM (_stencil_common.stencil_vmem_limit), since tiles this large
+# outgrow the 16 MiB scoped default.
+builder.tune("block_z", (4, 8, 16, 32), default=32)
+builder.tune("block_y", (8, 16, 32, 64, 128, 256), default=32)
 builder.tune("traversal", ("zy", "yz"), default="zy")
 builder.tune("unroll_z", (1, 2, 4), default=1)
 builder.tune("fuse_outputs", (True, False), default=True)
@@ -50,14 +54,9 @@ def _axis_shifts(view: FieldView, rows):
 
 
 def _fused_kernel(unroll_z, *refs):
-    (scal_ref,
-     u_refs, v_refs, w_refs, e_refs,
-     ut_ref, vt_ref, wt_ref) = (refs[0], refs[1:6], refs[6:11], refs[11:16],
-                                refs[16:21], refs[21], refs[22], refs[23])
+    scal_ref, (ut_ref, vt_ref, wt_ref) = refs[0], refs[-3:]
     dxi, dyi, dzi = scal_ref[0, 0], scal_ref[0, 1], scal_ref[0, 2]
-    views = [FieldView.from_refs(*rs) for rs in (u_refs, v_refs, w_refs,
-                                                 e_refs)]
-    fu, fv, fw, fe = views
+    fu, fv, fw, fe = field_views(refs[1:-3], [BOTH] * 4)
     bz = fu.bz
     rows_per = bz // unroll_z
     for c in range(unroll_z):
@@ -70,11 +69,9 @@ def _fused_kernel(unroll_z, *refs):
 
 
 def _single_kernel(unroll_z, *refs):
-    (scal_ref, f_refs, e_refs, out_ref) = (refs[0], refs[1:6], refs[6:11],
-                                           refs[11])
+    scal_ref, out_ref = refs[0], refs[-1]
     dxi, dyi, dzi = scal_ref[0, 0], scal_ref[0, 1], scal_ref[0, 2]
-    ff = FieldView.from_refs(*f_refs)
-    fe = FieldView.from_refs(*e_refs)
+    ff, fe = field_views(refs[1:-1], [BOTH] * 2)
     bz = ff.bz
     rows_per = bz // unroll_z
     for c in range(unroll_z):
@@ -82,17 +79,6 @@ def _single_kernel(unroll_z, *refs):
         ft = _ref.diff_field(*_axis_shifts(ff, rows), *_axis_shifts(fe, rows),
                              dxi, dyi, dzi)
         out_ref[rows] = ft.astype(out_ref.dtype)
-
-
-def _compiler_kwargs(config, interpret):
-    # Gated on the active DeviceSpec.backend (not on whether pltpu
-    # merely imports): Mosaic dimension_semantics reach only a TPU
-    # lowering, Triton warps/stages only a GPU one.
-    return lowering_kwargs(
-        dimension_semantics=(config["dim_semantics"],) * 2,
-        num_warps=8 if config["block_y"] >= 64 else 4,
-        num_stages=min(4, 1 + config["unroll_z"]),
-        interpret=interpret)
 
 
 @builder.build
@@ -107,9 +93,10 @@ def _build(config, problem, meta, interpret: bool = False):
     ospec = out_spec(problem, bz, by, to_zy)
     dtype = meta[0].dtype
     oshape = jax.ShapeDtypeStruct((nz, ny, nx), dtype)
-    kwargs = _compiler_kwargs(config, interpret)
 
     if config["fuse_outputs"]:
+        kwargs = stencil_lowering("diff_uvw", config, problem, [BOTH] * 4,
+                                  n_out_fields=3, interpret=interpret)
         call = pl.pallas_call(
             functools.partial(_fused_kernel, config["unroll_z"]),
             grid=grid,
@@ -119,12 +106,13 @@ def _build(config, problem, meta, interpret: bool = False):
             interpret=interpret, **kwargs)
 
         def run(u, v, w, evisc, scal):
-            reps = lambda f: (f,) * 5  # noqa: E731
-            return tuple(call(scal, *reps(u), *reps(v), *reps(w),
-                              *reps(evisc)))
+            return tuple(call(scal, *operands((u, v, w, evisc),
+                                              [BOTH] * 4)))
 
         return run
 
+    kwargs = stencil_lowering("diff_uvw", config, problem, [BOTH] * 2,
+                              n_out_fields=1, interpret=interpret)
     call = pl.pallas_call(
         functools.partial(_single_kernel, config["unroll_z"]),
         grid=grid,
@@ -134,8 +122,7 @@ def _build(config, problem, meta, interpret: bool = False):
         interpret=interpret, **kwargs)
 
     def run(u, v, w, evisc, scal):
-        reps = lambda f: (f,) * 5  # noqa: E731
-        return tuple(call(scal, *reps(f), *reps(evisc))
+        return tuple(call(scal, *operands((f, evisc), [BOTH] * 2))
                      for f in (u, v, w))
 
     return run

@@ -44,22 +44,26 @@ def v5e(monkeypatch):
     monkeypatch.setenv("KERNEL_LAUNCHER_DEVICE", "tpu-v5e")
 
 
-def _compile_default(name, shapes, one_chip):
+def _compile_default(name, shapes, one_chip, update=None):
     b = get_kernel(name)
     meta = tuple(jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                  for s, d in shapes)
-    fn = b.make(b.default_config(), meta)
+    fn = b.make(b.default_config() | (update or {}), meta)
     return jax.jit(fn).lower(*meta).compile()
 
 
 @pytest.mark.parametrize("name", ["advec_u", "diff_uvw"])
-@pytest.mark.parametrize("n,dtype", [(256, jnp.float32),
-                                     (256, jnp.bfloat16),
-                                     (512, jnp.float32)])
-def test_stencil_default_compiles(name, n, dtype, one_chip, v5e):
+@pytest.mark.parametrize("n,dtype,tile", [
+    pytest.param(256, jnp.float32, None, id="256-float32"),
+    pytest.param(256, jnp.bfloat16, None, id="256-bfloat16"),
+    pytest.param(512, jnp.float32, None, id="512-float32"),
+    # the former default, still in the tuning space
+    pytest.param(512, jnp.float32, {"block_z": 8, "block_y": 16},
+                 id="512-float32-8x16")])
+def test_stencil_default_compiles(name, n, dtype, tile, one_chip, v5e):
     n_fields = 3 if name == "advec_u" else 4
     shapes = [((n, n, n), dtype)] * n_fields + [((1, 4), jnp.float32)]
-    compiled = _compile_default(name, shapes, one_chip)
+    compiled = _compile_default(name, shapes, one_chip, tile)
     assert "tpu_custom_call" in compiled.as_text()
 
 

@@ -41,14 +41,18 @@ def tuned():
     return best
 
 
-def test_c1_default_far_from_optimum(tuned):
+def test_c1_default_far_from_optimum():
+    # against the optimum itself (every valid config scored), not a
+    # random search's estimate, which can miss it by more than 10 %
     b = get_kernel("advec_u")
+    fracs = []
     for sc in SCENARIOS:
-        default_t = evaluator(sc)(b.default_config()).score_us
-        best_t = tuned[sc.key][1]
+        ev = evaluator(sc)
+        default_t = ev(b.default_config()).score_us
+        best_t = min(ev(c).score_us for c in b.space.enumerate()
+                     if b.space.is_valid(c))
         assert best_t < default_t, sc.key
-    fracs = [tuned[sc.key][1] / evaluator(sc)(b.default_config()).score_us
-             for sc in SCENARIOS]
+        fracs.append(best_t / default_t)
     assert np.mean(fracs) < 0.9   # tuning buys >10% on average
 
 
